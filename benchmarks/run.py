@@ -77,6 +77,9 @@ def main() -> int:
     if only is not None and only not in suites:
         print(f"unknown suite {only!r}; valid: {', '.join(suites)}", file=sys.stderr)
         return 2
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name, fn in suites.items():

@@ -16,8 +16,9 @@ CORRECT) is structural and checkable before any kernel runs:
 - **out_specs/out_shape cardinality agree** when both are lists.
 - **estimated VMEM footprint under budget**: sum of block-spec and scratch
   bytes (double-buffered), resolving block names through local assignments
-  and module constants (unknown names assume ``ASSUMED_DIM``) — a coarse
-  gate that catches order-of-magnitude mistakes, not a cycle model.
+  and module constants (an unknown dim is assumed ``ASSUMED_DIM`` in the
+  two minor, tiled dims and ``ASSUMED_LEADING_DIM`` before them) — a
+  coarse gate that catches order-of-magnitude mistakes, not a cycle model.
 """
 
 from __future__ import annotations
@@ -28,7 +29,8 @@ from typing import Iterable
 from repro.analysis.engine import BaseChecker, Finding, dotted_name
 
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024   # ~16 MB/core (pallas guide)
-ASSUMED_DIM = 128                      # fallback for unresolvable dims
+ASSUMED_DIM = 128                      # fallback for unresolvable minor dims
+ASSUMED_LEADING_DIM = 8                # ... and for unresolvable leading dims
 ASSUMED_DTYPE_BYTES = 4
 
 
@@ -351,7 +353,7 @@ class PallasContractChecker(BaseChecker):
                 self.id, path, call.lineno,
                 f"estimated VMEM footprint ~{total / 2**20:.1f} MiB exceeds "
                 f"the {self.vmem_budget / 2**20:.0f} MiB budget (blocks "
-                f"double-buffered, unknown dims assumed {ASSUMED_DIM}) in "
+                f"double-buffered, unknown minor dims assumed {ASSUMED_DIM}) in "
                 f"`{fn.name}` — shrink the block sizes",
                 severity="warning", col=call.col_offset)
 
@@ -361,7 +363,12 @@ class PallasContractChecker(BaseChecker):
         if not isinstance(shape, (ast.Tuple, ast.List)):
             return 0
         n = 1
-        for d in shape.elts:
+        minor = len(shape.elts) - 2
+        for k, d in enumerate(shape.elts):
             v = _resolve(d, info, consts)
-            n *= v if v is not None and v > 0 else ASSUMED_DIM
+            if v is None or v <= 0:
+                # a whole (sublane, lane) tile in the minor dims; a leading
+                # dim of a multi-dim block is a head or row count, not a tile
+                v = ASSUMED_DIM if k >= minor else ASSUMED_LEADING_DIM
+            n *= v
         return n * ASSUMED_DTYPE_BYTES

@@ -14,7 +14,9 @@ Quantized leaves (QuantizedTensor) flatten to their ``.../qvalues`` and
 manifest additionally records each leaf's quantization format name and
 group size (``quant`` key) and restore refuses a tree whose declared
 formats disagree — a packed-int4 qvalues array silently reinterpreted as
-int8 rows would be shape-valid but numerically garbage.
+int8 rows would be shape-valid but numerically garbage. For the same reason
+restore refuses int4/int3 leaves from a manifest older than format 2, which
+packed them in an order the current unpack would misread.
 """
 
 from __future__ import annotations
@@ -31,6 +33,10 @@ from repro.core.treepath import path_str
 
 MANIFEST = "manifest.json"
 ARRAYS = "arrays.npz"
+# manifest format: 2 = int4/int3 qvalues in the group-local storage orders
+# of core/quant.py (format 1 packed runs of consecutive elements)
+FORMAT = 2
+_REORDERED_FORMATS = ("int4", "int3")
 
 
 def _flatten_with_paths(tree):
@@ -69,7 +75,7 @@ def save(directory: str, step: int, tree, extra: dict | None = None) -> str:
         "keys": sorted(arrays.keys()),
         "extra": extra or {},
         "quant": _quant_meta(tree),
-        "format": 1,
+        "format": FORMAT,
     }
     with open(os.path.join(tmp, MANIFEST), "w") as f:
         json.dump(manifest, f)
@@ -105,6 +111,7 @@ def restore(directory: str, like, step: int | None = None):
 
     saved_q = manifest.get("quant")
     if saved_q is not None:
+        old_order = manifest.get("format", 1) < FORMAT
         for key, meta in _quant_meta(like).items():
             got = saved_q.get(key)
             if got is not None and got != meta:
@@ -113,6 +120,11 @@ def restore(directory: str, like, step: int | None = None):
                     f"{got}, restore target expects {meta} — requantize "
                     "instead of reinterpreting packed qvalues"
                 )
+            if got is not None and old_order and got["fmt"] in _REORDERED_FORMATS:
+                raise ValueError(
+                    f"checkpoint format {manifest.get('format', 1)} packs "
+                    f"{key} in an older int4/int3 storage order — "
+                    "requantize from float weights")
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(like)
     leaves = []

@@ -48,7 +48,7 @@ def embedding_lookup(w, ids: jax.Array, dtype=jnp.float32) -> jax.Array:
     if isinstance(w, QuantizedTensor):
         q = jnp.take(w.qvalues, ids, axis=0)        # (..., d/pack) storage
         s = jnp.take(w.scales, ids, axis=0)         # (..., d/GS)
-        v = w.format.unpack_values(q)               # (..., d) int8 values
+        v = w.format.unpack_values(q, w.group_size)   # (..., d) int8 values
         g = v.reshape(*v.shape[:-1], w.num_groups, w.group_size).astype(dtype)
         return (g * s[..., None].astype(dtype)).reshape(v.shape)
     return jnp.take(w, ids, axis=0).astype(dtype)

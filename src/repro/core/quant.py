@@ -13,10 +13,12 @@ as a :class:`QuantFormat` REGISTRY instead of hardwiring int8:
 
   int8   storage int8, 1 value/byte, range [-127, 127]  (paper behavior,
          bit-identical to the original ``quantize_groupwise``)
-  int4   storage int8, 2 nibbles/byte packed along the last axis,
-         range [-7, 7] — halves weight HBM traffic per decode step
+  int4   storage int8, 2 nibbles/byte packed along the last axis (the two
+         halves of each group share bytes), range [-7, 7] — halves weight
+         HBM traffic per decode step
   int3   storage uint8, 8 values per 3 bytes (true 3-bit packing, no pow2
-         padding), range [-3, 3] — 0.375 B/weight, below the int4 floor
+         padding; three byte planes per group), range [-3, 3] —
+         0.375 B/weight, below the int4 floor
   fp8    storage float8_e4m3fn, 1 value/byte, per-group scale S=absmax/448
          (the e4m3 max-finite) — int8's byte cost with a float value grid
 
@@ -31,8 +33,8 @@ The quantized weight of a (m, n) matrix is stored like the paper's
 flattened ``wq``/``ws`` arrays, kept 2-D for JAX/sharding friendliness:
 
   qvalues : storage dtype (m, n // pack)  -- row-major, groups along n,
-                                             packed formats pair adjacent
-                                             elements within a group
+                                             packed formats keep each
+                                             group's bytes contiguous
   scales  : float32 (m, n // GS)          -- one scale per (row, group)
 
 Activations are always quantized at run time to int8 along their last axis
@@ -69,8 +71,10 @@ __all__ = [
     "quantize_int4",
     "quantize_int3",
     "quantize_fp8",
+    "int4_halves",
     "pack_int4",
     "unpack_int4",
+    "int3_fields",
     "pack_int3",
     "unpack_int3",
     "FP8_MAX",
@@ -266,13 +270,16 @@ class QuantFormat:
             _numerics_guard(f"dequantize[{self.name}].output", out)
         return out
 
-    def unpack_values(self, qvalues: jax.Array) -> jax.Array:
+    def unpack_values(self, qvalues: jax.Array, group_size: int) -> jax.Array:
         """Storage array -> logical values (int8 for integer formats, the
-        storage dtype itself for float formats; identity when pack == 1)."""
-        return qvalues if self.unpack_fn is None else self.unpack_fn(qvalues)
+        storage dtype itself for float formats; identity when pack == 1).
+        Packed layouts are group-local, so unpacking needs the group size."""
+        return (qvalues if self.unpack_fn is None
+                else self.unpack_fn(qvalues, group_size))
 
-    def pack_values(self, values: jax.Array) -> jax.Array:
-        return values if self.pack_fn is None else self.pack_fn(values)
+    def pack_values(self, values: jax.Array, group_size: int) -> jax.Array:
+        return (values if self.pack_fn is None
+                else self.pack_fn(values, group_size))
 
 
 _FORMATS: dict[str, QuantFormat] = {}
@@ -382,26 +389,47 @@ def _dequantize_int8(qt: QuantizedTensor, dtype=jnp.float32) -> jax.Array:
 # ---------------------------------------------------------------------------
 # int4, packed two nibbles per int8 byte (W4A8)
 # ---------------------------------------------------------------------------
+# Layouts of the sub-byte formats are chosen for the TPU kernel: there each
+# unpack is shifts on int32 (the v5e vector unit has no 8-bit shifts) plus
+# one lane-aligned concatenation, never a lane interleave; the XLA unpacks
+# below apply the same shifts as one broadcast over a field axis. Both
+# layouts are group-local, so a storage slice of whole groups is a shard of
+# whole groups (dist/sharding.py).
 
-def pack_int4(q: jax.Array) -> jax.Array:
+_INT4_SHIFTS = (28, 24)      # low, high nibble: (byte << s) >>a 28
+
+
+def int4_halves(p: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Packed int4 bytes -> (low, high) nibble values, sign-extended int8."""
+    w = p.astype(jnp.int32)
+    return tuple(((w << s) >> 28).astype(jnp.int8) for s in _INT4_SHIFTS)
+
+
+def pack_int4(q: jax.Array, group_size: int) -> jax.Array:
     """int8 logical values in [-7, 7], (..., n) -> packed int8 (..., n // 2).
 
-    Byte i holds element 2i in its low nibble and element 2i+1 in its high
-    nibble; adjacent elements pair up, so any even group size keeps every
-    byte inside one quantization group (the sharding invariant).
-    """
-    if q.shape[-1] % 2:
-        raise ValueError(f"int4 packing needs an even last axis, got {q.shape}")
-    lo = jnp.bitwise_and(q[..., 0::2], 0x0F)
-    hi = jnp.left_shift(q[..., 1::2], 4)            # int8 shift wraps mod 256
-    return jnp.bitwise_or(lo, hi)
+    Within each group of ``group_size`` elements, byte k holds element k in
+    its low nibble and element k + group_size/2 in its high nibble."""
+    n = q.shape[-1]
+    if group_size % 2 or n % group_size:
+        raise ValueError(f"int4 packing needs an even group_size dividing the "
+                         f"last axis, got {q.shape} with group_size={group_size}")
+    g = q.reshape(*q.shape[:-1], n // group_size, 2, group_size // 2)
+    lo = jnp.bitwise_and(g[..., 0, :], 0x0F)
+    hi = jnp.left_shift(g[..., 1, :], 4)            # int8 shift wraps mod 256
+    return jnp.bitwise_or(lo, hi).reshape(*q.shape[:-1], n // 2)
 
 
-def unpack_int4(p: jax.Array) -> jax.Array:
-    """Packed int8 (..., n // 2) -> sign-extended int8 logical values (..., n)."""
-    lo = jnp.right_shift(jnp.left_shift(p, 4), 4)   # arithmetic >> sign-extends
-    hi = jnp.right_shift(p, 4)
-    return jnp.stack([lo, hi], axis=-1).reshape(*p.shape[:-1], p.shape[-1] * 2)
+def unpack_int4(p: jax.Array, group_size: int) -> jax.Array:
+    """Packed int8 (..., n // 2) -> sign-extended int8 logical values (..., n).
+
+    Both halves come from one broadcast shift, with no concatenation, so XLA
+    fuses the whole decode into one loop — the form the xray bytes audit
+    (analysis/hlo.py ``is_unpack_fusion``) normalizes to a packed read."""
+    w = p.reshape(*p.shape[:-1], -1, 1, group_size // 2).astype(jnp.int32)
+    shifts = jnp.asarray(_INT4_SHIFTS, jnp.int32)[:, None]
+    v = ((w << shifts) >> 28).astype(jnp.int8)        # (..., G, 2, GS/2)
+    return v.reshape(*p.shape[:-1], p.shape[-1] * 2)
 
 
 @partial(jax.jit, static_argnames=("group_size",))
@@ -416,13 +444,14 @@ def quantize_int4(r: jax.Array, group_size: int = DEFAULT_GROUP_SIZE) -> Quantiz
         raise ValueError(f"int4 needs an even group_size, got {group_size}")
     q, scales = _group_quantize(r, group_size, qmax=7)
     return QuantizedTensor(
-        qvalues=pack_int4(q), scales=scales, group_size=group_size, fmt="int4"
+        qvalues=pack_int4(q, group_size), scales=scales, group_size=group_size,
+        fmt="int4",
     )
 
 
 @partial(jax.jit, static_argnames=("dtype",))
 def _dequantize_int4(qt: QuantizedTensor, dtype=jnp.float32) -> jax.Array:
-    vals = unpack_int4(qt.qvalues)
+    vals = unpack_int4(qt.qvalues, qt.group_size)
     g = vals.reshape(*vals.shape[:-1], qt.num_groups, qt.group_size)
     out = g.astype(jnp.float32) * qt.scales[..., None]
     return out.reshape(vals.shape).astype(dtype)
@@ -432,54 +461,59 @@ def _dequantize_int4(qt: QuantizedTensor, dtype=jnp.float32) -> jax.Array:
 # int3, true 3-bit packing: 8 values per 3 bytes (W3A8)
 # ---------------------------------------------------------------------------
 # Pow2-padding 3-bit fields to nibbles would store int3 at int4's byte cost
-# and erase the whole point; instead eight 3-bit two's-complement fields are
-# packed little-endian into one 24-bit word (3 uint8 storage bytes). pack=8
-# divides every power-of-two group size >= 8, so the whole-groups sharding
-# invariant holds with no new geometry at the policy layer.
+# and erase the whole point; instead eight 3-bit two's-complement fields
+# share one 24-bit word (3 uint8 storage bytes). Each group of GS elements
+# is stored as three byte PLANES of w = GS/8 bytes: the 24-bit word k is
+# plane0[k] | plane1[k] << 8 | plane2[k] << 16, and its field c (bits
+# 3c..3c+2) is group element c*w + k. Unpacking is then shifts on whole
+# planes and one concatenation of the eight field planes.
 
-def pack_int3(q: jax.Array) -> jax.Array:
-    """int8 logical values in [-3, 3], (..., n) -> packed uint8 (..., n//8*3).
+_INT3_SHIFTS = tuple(29 - 3 * c for c in range(8))   # field c: (u << s) >>a 29
 
-    Each run of 8 elements becomes one 24-bit little-endian word: element i
-    occupies bits [3i, 3i+3) as a 3-bit two's-complement field; the word is
-    stored as 3 bytes (b0 = bits 0-7, b1 = 8-15, b2 = 16-23)."""
-    if q.shape[-1] % 8:
-        raise ValueError(f"int3 packing needs a last axis divisible by 8, got {q.shape}")
+
+def int3_fields(b0: jax.Array, b1: jax.Array, b2: jax.Array) -> list[jax.Array]:
+    """Three uint8 byte planes -> the eight 3-bit field planes, sign-extended
+    int8: the TPU kernel's decode. Each field is cut from a 16-bit word,
+    fields 0-4 from ``b0 | b1 << 8`` and fields 5-7 from ``b1 | b2 << 8``:
+    on a TPU v5e the kernel compiler drops the bits of ``b2 << 16`` (the
+    24-bit word of :func:`unpack_int3`), which decoded fields 5-7 wrong on
+    the chip while interpret mode was exact."""
+    b0, b1, b2 = (b.astype(jnp.int32) for b in (b0, b1, b2))
+    lo, hi = b0 | (b1 << 8), b1 | (b2 << 8)
+    return [((lo << (29 - 3 * c)) >> 29).astype(jnp.int8) for c in range(5)] + [
+        ((hi << (37 - 3 * c)) >> 29).astype(jnp.int8) for c in range(5, 8)]
+
+
+def pack_int3(q: jax.Array, group_size: int) -> jax.Array:
+    """int8 logical values in [-3, 3], (..., n) -> packed uint8 (..., n//8*3)
+    in the per-group plane layout above."""
+    n = q.shape[-1]
+    if group_size % 8 or n % group_size:
+        raise ValueError(f"int3 packing needs a group_size divisible by 8 that "
+                         f"divides the last axis, got {q.shape} with "
+                         f"group_size={group_size}")
+    w = group_size // 8
     u = jnp.bitwise_and(q.astype(jnp.int32), 0x7)
-    u = u.reshape(*q.shape[:-1], q.shape[-1] // 8, 8)
-    w = jnp.sum(jnp.left_shift(u, jnp.arange(8, dtype=jnp.int32) * 3), axis=-1)
-    b = jnp.stack([w & 0xFF, (w >> 8) & 0xFF, (w >> 16) & 0xFF], axis=-1)
-    return b.astype(jnp.uint8).reshape(*q.shape[:-1], q.shape[-1] // 8 * 3)
+    u = u.reshape(*q.shape[:-1], n // group_size, 8, w)
+    word = jnp.sum(jnp.left_shift(u, jnp.arange(8, dtype=jnp.int32)[:, None] * 3),
+                   axis=-2)                                      # (..., G, w)
+    planes = jnp.stack([word & 0xFF, (word >> 8) & 0xFF, (word >> 16) & 0xFF],
+                       axis=-2)                                  # (..., G, 3, w)
+    return planes.astype(jnp.uint8).reshape(*q.shape[:-1], n // 8 * 3)
 
 
-def unpack_int3(p: jax.Array) -> jax.Array:
-    """Packed uint8 (..., 3k) -> sign-extended int8 logical values (..., 8k).
-
-    Pure shift/mask/interleave: each 3-bit field of the little-endian 24-bit
-    group comes straight off its byte plane(s), and sign extension is the
-    ``(v << 5) >>a 5`` trick on a bitcast int8 view — no select/subtract.
-    This is not a style choice: the xray bytes audit (analysis/hlo.py
-    ``is_unpack_fusion``) only normalizes unpack fusions whose body is free
-    of arithmetic, the contract that the TPU dot reads the PACKED buffer.
-    An unpack with compares/subtracts is charged at full s32 width and
-    int3 decode would audit at ~8x its declared traffic.
-    """
-    if p.shape[-1] % 3:
-        raise ValueError(f"int3 storage last axis must divide by 3, got {p.shape}")
-    b = p.reshape(*p.shape[:-1], p.shape[-1] // 3, 3)
-    b0, b1, b2 = b[..., 0], b[..., 1], b[..., 2]
-    fields = [
-        b0 & 7,                                  # bits 0-2
-        (b0 >> 3) & 7,                           # bits 3-5
-        ((b0 >> 6) & 3) | ((b1 << 2) & 4),       # bits 6-8 straddle b0/b1
-        (b1 >> 1) & 7,                           # bits 9-11
-        (b1 >> 4) & 7,                           # bits 12-14
-        ((b1 >> 7) & 1) | ((b2 << 1) & 6),       # bits 15-17 straddle b1/b2
-        (b2 >> 2) & 7,                           # bits 18-20
-        (b2 >> 5) & 7,                           # bits 21-23
-    ]
-    u = jnp.stack(fields, axis=-1)               # (..., k, 8) uint8 in 0..7
-    v = jax.lax.bitcast_convert_type(u << 5, jnp.int8) >> 5
+def unpack_int3(p: jax.Array, group_size: int) -> jax.Array:
+    """Packed uint8 (..., n//8*3) -> sign-extended int8 logical values (..., n)."""
+    w = group_size // 8
+    if group_size % 8 or p.shape[-1] % (3 * w):
+        raise ValueError(f"int3 storage last axis must hold whole groups of "
+                         f"{3 * w} bytes, got {p.shape}")
+    g = p.reshape(*p.shape[:-1], -1, 3, w).astype(jnp.int32)
+    u = (g[..., 0, :] | (g[..., 1, :] << 8) | (g[..., 2, :] << 16))[..., None, :]
+    # all eight fields from one broadcast shift (one XLA loop fusion, as in
+    # unpack_int4)
+    shifts = jnp.asarray(_INT3_SHIFTS, jnp.int32)[:, None]
+    v = ((u << shifts) >> 29).astype(jnp.int8)        # (..., G, 8, w)
     return v.reshape(*p.shape[:-1], p.shape[-1] // 3 * 8)
 
 
@@ -494,13 +528,14 @@ def quantize_int3(r: jax.Array, group_size: int = DEFAULT_GROUP_SIZE) -> Quantiz
         raise ValueError(f"int3 needs a group_size divisible by 8, got {group_size}")
     q, scales = _group_quantize(r, group_size, qmax=3)
     return QuantizedTensor(
-        qvalues=pack_int3(q), scales=scales, group_size=group_size, fmt="int3"
+        qvalues=pack_int3(q, group_size), scales=scales, group_size=group_size,
+        fmt="int3",
     )
 
 
 @partial(jax.jit, static_argnames=("dtype",))
 def _dequantize_int3(qt: QuantizedTensor, dtype=jnp.float32) -> jax.Array:
-    vals = unpack_int3(qt.qvalues)
+    vals = unpack_int3(qt.qvalues, qt.group_size)
     g = vals.reshape(*vals.shape[:-1], qt.num_groups, qt.group_size)
     out = g.astype(jnp.float32) * qt.scales[..., None]
     return out.reshape(vals.shape).astype(dtype)

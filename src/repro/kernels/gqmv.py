@@ -1,39 +1,51 @@
-"""Pallas TPU kernels for group-wise quantized matrix-vector/matrix multiply.
+"""Pallas TPU kernel for group-wise quantized matrix multiply (GQMM), with
+the matrix-vector product (GQMV) as its one-row case.
 
 TPU adaptation of the paper's 3-stage pipelined FPGA accelerator (§IV):
 
   FPGA stage            TPU analogue (this file)
   -------------------   ----------------------------------------------------
-  pre-processing:       Pallas grid pipelining: each (bm, bn) int8 weight
-  DDR->BRAM streaming   block is DMA'd HBM->VMEM double-buffered while the
-  of wq/ws blocks       previous block computes  (paper C3, Fig. 2)
-  dot-product: SIMD     jax.lax.dot_general int8 x int8 with
-  mul + depth-8 adder   preferred_element_type=int32, batched over groups
-  tree per group        (the MXU/VPU reduction replaces the adder tree)
-  accumulate: fp32      group_sums * (ws * xs) in fp32, accumulated across
-  scale + writeback     n-blocks into the VMEM output block
+  pre-processing:       Pallas grid pipelining: each (bm, n) block of whole
+  DDR->BRAM streaming   weight rows is DMA'd HBM->VMEM double-buffered while
+  of wq/ws blocks       the previous block computes  (paper C3, Fig. 2)
+  dot-product: SIMD     one int8 x int8 -> int32 MXU dot per quantization
+  mul + depth-8 adder   group, (bb, GS) x (bm, GS)^T
+  tree per group        (the MXU reduction replaces the adder tree)
+  accumulate: fp32      group_sums * (xs * ws) in fp32, added group by group
+  scale + writeback     in order, then one write of the (bb, bm) out block
 
 Progressive INT8->INT16->INT32 widening from the paper is collapsed to
 int8 MACs with native int32 accumulation (FPGA DSP packing artifact; see
-DESIGN.md §2). Group size GS=256 = 2x128 TPU lanes, so group reductions
-are lane-aligned.
+DESIGN.md §2). Group size GS=256 = 2x128 TPU lanes, so every group slice
+is lane-aligned.
 
-Kernels are written for TPU (BlockSpec/VMEM) and validated on CPU with
-``interpret=True`` against ``ref.py``.
+Block layout (what the TPU compiler accepts):
 
-Four weight formats share the compute stages (see core/quant.py registry):
+  * a block holds WHOLE rows of ``wq`` (the full contraction axis), so the
+    per-group scales ``ws`` (bm, n/GS) and ``xs`` (bb, n/GS) are blocks of
+    the full group axis and every group index is static;
+  * ``ws`` is transposed in VMEM to (n/GS, bm), putting the output rows on
+    the lane axis, as in the (bb, bm) output block;
+  * the grid runs row blocks of ``wq`` outermost, so each weight byte is
+    streamed once per call; activation rows beyond one block are padded up
+    to a whole block.
+
+Four weight formats share the dot-product and accumulate stages (see
+core/quant.py registry); ``_GROUP_WEIGHTS`` holds each one's decode stage:
 
   int8  wq streamed as int8 blocks (the paper's layout)
   int4  wq streamed PACKED (two nibbles per byte, half the HBM traffic of
         int8 — the paper's §II-B bandwidth lever pushed below one byte) and
-        sign-extended to int8 nibble values in VMEM just before the group
-        dot. Only the DMA'd bytes shrink; the dot-product and accumulate
-        stages are byte-for-byte the int8 ones.
+        sign-extended to int8 in VMEM, one group at a time, just before the
+        group dot. Only the DMA'd bytes shrink.
   int3  wq streamed as true 3-bit packing (8 values per 3 uint8 bytes,
         0.375 B/weight) and sign-extended in VMEM — the sub-int4 point of
         the same streaming argument.
-  fp8   wq streamed as float8_e4m3fn bytes; the group dot runs in f32
-        (same VMEM blocks, float datapath instead of the int8 MACs).
+  fp8   wq streamed as float8_e4m3fn bytes; the group dot runs on bf16
+        operands with f32 accumulation (e4m3 and int8 values are exact in
+        bf16).
+
+Validated on CPU with ``interpret=True`` against ``ref.py``.
 """
 
 from __future__ import annotations
@@ -44,334 +56,131 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.quant import unpack_int3, unpack_int4
+from repro.core.quant import int3_fields, int4_halves
 
-DEFAULT_BM = 256   # output rows per block
-DEFAULT_BN = 1024  # contraction columns per block (multiple of GS)
-DEFAULT_BB = 128   # batch rows per block (GQMM)
+DEFAULT_BM = 256   # output rows per block (a multiple of the 128 lanes)
+DEFAULT_BB = 128   # activation rows per block
 
-_INT8_GROUP_DOT = (((2,), (1,)), ((0,), (0,)))  # (g,bm,GS) x (g,GS) -> (g,bm)
-
-
-def _pick_block(dim: int, preferred: int, multiple_of: int = 1) -> int:
-    """Largest block <= preferred that divides dim and is a multiple of
-    ``multiple_of`` (the quantization group size for the n axis)."""
-    cand = min(preferred, dim)
-    cand -= cand % multiple_of
-    while cand >= multiple_of:
-        if dim % cand == 0 and cand % multiple_of == 0:
-            return cand
-        cand -= multiple_of
-    if multiple_of == 1:
-        return 1
-    raise ValueError(f"no block for dim={dim} multiple_of={multiple_of}")
+_NT = (((1,), (1,)), ((), ()))   # (bb, GS) x (bm, GS) -> (bb, bm)
 
 
-def _check_divides(dim: int, blk: int, axis: str, multiple_of: int = 1) -> int:
+def _check_divides(dim: int, blk: int, axis: str) -> int:
     """Validate a (possibly caller-supplied) block size: the grid is built
     as ``dim // blk``, so a non-dividing block would silently drop the tail
-    rows; the n axis must additionally stay a whole number of quantization
-    groups / storage elements."""
-    if dim % blk or blk % multiple_of:
+    rows."""
+    if dim % blk:
         raise ValueError(
-            f"block {blk} invalid for {axis}={dim} "
-            f"(multiple_of={multiple_of}): the grid would drop the tail")
+            f"block {blk} invalid for {axis}={dim}: the grid would drop the tail")
     return blk
 
 
-# ---------------------------------------------------------------------------
-# GQMV: out (1, m)  =  W(q) (m, n)  @  x(q) (1, n)     -- paper's batch-1 core
-# ---------------------------------------------------------------------------
-
-def _gqmv_compute(wq, xq_ref, xs_ref, ws_ref, out_ref, *, group_size: int):
-    """Dot-product + accumulate stages shared by every weight format; ``wq``
-    is the already-unpacked (bm, bn) weight block in VMEM — int8 values for
-    the integer formats, float8 for fp8 (the dot then runs in f32)."""
-    j = pl.program_id(1)           # n-block index (innermost grid dim)
-    bm, bn = wq.shape
-    ng = bn // group_size
-    integer = jnp.issubdtype(wq.dtype, jnp.integer)
-
-    # --- dot-product stage: int8 x int8 -> int32 group sums (fp8: f32) -----
-    wg = wq.reshape(bm, ng, group_size).transpose(1, 0, 2)            # (g,bm,GS)
-    xg = xq_ref[0].reshape(ng, group_size)                            # (g,GS)
-    if not integer:
-        wg, xg = wg.astype(jnp.float32), xg.astype(jnp.float32)
-    group_sums = jax.lax.dot_general(
-        wg, xg, _INT8_GROUP_DOT,
-        preferred_element_type=jnp.int32 if integer else jnp.float32,
-    )                                                                 # (g,bm)
-
-    # --- accumulate stage: fp32 scale and cross-group reduction ------------
-    scale = ws_ref[...] * xs_ref[0][None, :]                          # (bm,g)
-    partial = jnp.sum(group_sums.astype(jnp.float32).T * scale, axis=-1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[0, :] = partial
-
-    @pl.when(j > 0)
-    def _acc():
-        out_ref[0, :] += partial
-
-
-def _gqmv_kernel(xq_ref, xs_ref, wq_ref, ws_ref, out_ref, *, group_size: int):
-    _gqmv_compute(wq_ref[...], xq_ref, xs_ref, ws_ref, out_ref,
-                  group_size=group_size)
-
-
-def _gqmv_int4_kernel(xq_ref, xs_ref, wp_ref, ws_ref, out_ref, *, group_size: int):
-    # pre-processing stage streamed half the bytes; sign-extend in VMEM
-    _gqmv_compute(unpack_int4(wp_ref[...]), xq_ref, xs_ref, ws_ref, out_ref,
-                  group_size=group_size)
-
-
-def _gqmv_int3_kernel(xq_ref, xs_ref, wp_ref, ws_ref, out_ref, *, group_size: int):
-    # 3 streamed bytes carry 8 weights; sign-extend the 3-bit fields in VMEM
-    _gqmv_compute(unpack_int3(wp_ref[...]), xq_ref, xs_ref, ws_ref, out_ref,
-                  group_size=group_size)
-
-
-def _gqmv_call(kernel, wq, ws, xq, xs, *, group_size, pack,
-               block_m, block_n, interpret, pack_storage=1):
-    """Shared pallas_call plumbing; pack geometry is ``pack`` logical
-    elements per ``pack_storage`` storage elements (wq's trailing axis holds
-    n // pack * pack_storage storage elements)."""
-    m = wq.shape[0]
-    n = xq.shape[-1]
-    gmult = max(group_size, pack)
-    bm = _check_divides(m, block_m or _pick_block(m, DEFAULT_BM), "m")
-    bn = _check_divides(
-        n, block_n or _pick_block(n, DEFAULT_BN, multiple_of=gmult), "n",
-        multiple_of=gmult)
-    ng = bn // group_size
-    bw = bn // pack * pack_storage
-    grid = (m // bm, n // bn)
-
-    return pl.pallas_call(
-        functools.partial(kernel, group_size=group_size),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),            # xq
-            pl.BlockSpec((1, ng), lambda i, j: (0, j)),            # xs
-            pl.BlockSpec((bm, bw), lambda i, j: (i, j)),           # wq (streamed)
-            pl.BlockSpec((bm, ng), lambda i, j: (i, j)),           # ws (streamed)
-        ],
-        out_specs=pl.BlockSpec((1, bm), lambda i, j: (0, i)),      # out row block
-        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
-        interpret=interpret,
-    )(xq[None, :], xs[None, :], wq, ws)[0]
-
-
-def gqmv_pallas(
-    wq: jax.Array,   # int8 (m, n)
-    ws: jax.Array,   # f32 (m, n // GS)
-    xq: jax.Array,   # int8 (n,)
-    xs: jax.Array,   # f32 (n // GS,)
-    *,
-    group_size: int,
-    block_m: int | None = None,
-    block_n: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    return _gqmv_call(_gqmv_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=1, block_m=block_m, block_n=block_n,
-                      interpret=interpret)
-
-
-def gqmv_int4_pallas(
-    wq: jax.Array,   # int8 PACKED (m, n // 2)
-    ws: jax.Array,   # f32 (m, n // GS)
-    xq: jax.Array,   # int8 (n,)
-    xs: jax.Array,   # f32 (n // GS,)
-    *,
-    group_size: int,
-    block_m: int | None = None,
-    block_n: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    return _gqmv_call(_gqmv_int4_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=2, block_m=block_m, block_n=block_n,
-                      interpret=interpret)
-
-
-def gqmv_int3_pallas(
-    wq: jax.Array,   # uint8 PACKED (m, n // 8 * 3)
-    ws: jax.Array,   # f32 (m, n // GS)
-    xq: jax.Array,   # int8 (n,)
-    xs: jax.Array,   # f32 (n // GS,)
-    *,
-    group_size: int,
-    block_m: int | None = None,
-    block_n: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    return _gqmv_call(_gqmv_int3_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=8, pack_storage=3, block_m=block_m, block_n=block_n,
-                      interpret=interpret)
-
-
-def gqmv_fp8_pallas(
-    wq: jax.Array,   # float8_e4m3fn (m, n)
-    ws: jax.Array,   # f32 (m, n // GS)
-    xq: jax.Array,   # int8 (n,)
-    xs: jax.Array,   # f32 (n // GS,)
-    *,
-    group_size: int,
-    block_m: int | None = None,
-    block_n: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    # fp8 storage needs no unpack stage; the shared compute switches to the
-    # f32 datapath off the weight dtype.
-    return _gqmv_call(_gqmv_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=1, block_m=block_m, block_n=block_n,
-                      interpret=interpret)
+def _row_block(m: int) -> int:
+    """Output rows per block: a multiple of 128 (the output block's lane
+    axis) when m has one, else all of m (a full-dim block is always legal)."""
+    return next((bm for bm in (DEFAULT_BM, 128) if m % bm == 0), m)
 
 
 # ---------------------------------------------------------------------------
-# GQMM: out (b, m) = X(q) (b, n) @ W(q)^T -- batched prefill / batched decode
+# decode stage: storage block -> group g's (bm, GS) weight values in VMEM
 # ---------------------------------------------------------------------------
 
-def _gqmm_compute(wq, xq_ref, xs_ref, ws_ref, out_ref, *, group_size: int):
-    j = pl.program_id(2)           # n-block index (innermost)
-    bm, bn = wq.shape
-    bb = xq_ref.shape[0]
-    ng = bn // group_size
-
-    integer = jnp.issubdtype(wq.dtype, jnp.integer)
-    wg = wq.reshape(bm, ng, group_size).transpose(1, 0, 2)            # (g,bm,GS)
-    xg = xq_ref[...].reshape(bb, ng, group_size).transpose(1, 0, 2)   # (g,bb,GS)
-    if not integer:
-        wg, xg = wg.astype(jnp.float32), xg.astype(jnp.float32)
-    # (g,bb,GS) x (g,bm,GS) -> (g,bb,bm) int32 group sums (fp8: f32)
-    group_sums = jax.lax.dot_general(
-        xg, wg, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32 if integer else jnp.float32,
-    )
-    scaled = (
-        group_sums.astype(jnp.float32)
-        * xs_ref[...].T[:, :, None]          # (g,bb,1)
-        * ws_ref[...].T[:, None, :]          # (g,1,bm)
-    )
-    partial = jnp.sum(scaled, axis=0)        # (bb, bm)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = partial
-
-    @pl.when(j > 0)
-    def _acc():
-        out_ref[...] += partial
+def _plain_group(w_ref, g: int, gs: int):
+    return w_ref[:, g * gs:(g + 1) * gs]
 
 
-def _gqmm_kernel(xq_ref, xs_ref, wq_ref, ws_ref, out_ref, *, group_size: int):
-    _gqmm_compute(wq_ref[...], xq_ref, xs_ref, ws_ref, out_ref,
-                  group_size=group_size)
+def _int4_group(w_ref, g: int, gs: int):
+    h = gs // 2                       # group g's bytes hold both halves
+    return jnp.concatenate(int4_halves(w_ref[:, g * h:(g + 1) * h]), axis=-1)
 
 
-def _gqmm_int4_kernel(xq_ref, xs_ref, wp_ref, ws_ref, out_ref, *, group_size: int):
-    _gqmm_compute(unpack_int4(wp_ref[...]), xq_ref, xs_ref, ws_ref, out_ref,
-                  group_size=group_size)
+def _int3_group(w_ref, g: int, gs: int):
+    w = gs // 8                       # three byte planes of w bytes per group
+    o = 3 * w * g
+    planes = (w_ref[:, o:o + w], w_ref[:, o + w:o + 2 * w],
+              w_ref[:, o + 2 * w:o + 3 * w])
+    return jnp.concatenate(int3_fields(*planes), axis=-1)
 
 
-def _gqmm_int3_kernel(xq_ref, xs_ref, wp_ref, ws_ref, out_ref, *, group_size: int):
-    _gqmm_compute(unpack_int3(wp_ref[...]), xq_ref, xs_ref, ws_ref, out_ref,
-                  group_size=group_size)
+_GROUP_WEIGHTS = {"int8": _plain_group, "fp8": _plain_group,
+                  "int4": _int4_group, "int3": _int3_group}
 
 
-def _gqmm_call(kernel, wq, ws, xq, xs, *, group_size, pack,
-               block_b, block_m, block_n, interpret, pack_storage=1):
-    m = wq.shape[0]
-    b, n = xq.shape
-    gmult = max(group_size, pack)
-    bb = _check_divides(b, block_b or _pick_block(b, DEFAULT_BB), "b")
-    bm = _check_divides(m, block_m or _pick_block(m, DEFAULT_BM), "m")
-    bn = _check_divides(
-        n, block_n or _pick_block(n, DEFAULT_BN, multiple_of=gmult), "n",
-        multiple_of=gmult)
-    ng = bn // group_size
-    bw = bn // pack * pack_storage
-    grid = (b // bb, m // bm, n // bn)
-
-    return pl.pallas_call(
-        functools.partial(kernel, group_size=group_size),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, bn), lambda ib, im, j: (ib, j)),          # xq
-            pl.BlockSpec((bb, ng), lambda ib, im, j: (ib, j)),          # xs
-            pl.BlockSpec((bm, bw), lambda ib, im, j: (im, j)),          # wq
-            pl.BlockSpec((bm, ng), lambda ib, im, j: (im, j)),          # ws
-        ],
-        out_specs=pl.BlockSpec((bb, bm), lambda ib, im, j: (ib, im)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
-        interpret=interpret,
-    )(xq, xs, wq, ws)
+def _gqmm_kernel(xq_ref, xs_ref, wq_ref, ws_ref, out_ref, *, group_size: int,
+                 group_weights):
+    xs = xs_ref[...]                  # (bb, G)
+    ws = ws_ref[...].T                # (G, bm): rows on lanes, like out
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for g in range(xs.shape[1]):
+        w = group_weights(wq_ref, g, group_size)                     # (bm, GS)
+        x = xq_ref[:, g * group_size:(g + 1) * group_size]           # (bb, GS)
+        if jnp.issubdtype(w.dtype, jnp.integer):
+            sums = jax.lax.dot_general(x, w, _NT,
+                                       preferred_element_type=jnp.int32)
+        else:
+            sums = jax.lax.dot_general(x.astype(jnp.bfloat16),
+                                       w.astype(jnp.bfloat16), _NT,
+                                       preferred_element_type=jnp.float32)
+        acc = acc + sums.astype(jnp.float32) * (xs[:, g:g + 1] * ws[g:g + 1, :])
+    out_ref[...] = acc
 
 
 def gqmm_pallas(
-    wq: jax.Array,   # int8 (m, n)
+    wq: jax.Array,   # storage (m, n // pack * pack_storage)
     ws: jax.Array,   # f32 (m, n // GS)
     xq: jax.Array,   # int8 (b, n)
     xs: jax.Array,   # f32 (b, n // GS)
     *,
     group_size: int,
-    block_b: int | None = None,
+    fmt: str = "int8",
     block_m: int | None = None,
-    block_n: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    return _gqmm_call(_gqmm_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=1, block_b=block_b, block_m=block_m,
-                      block_n=block_n, interpret=interpret)
+    """out (b, m) f32 = X(q) (b, n) @ W(q)^T for weights stored in registry
+    format ``fmt``; b = tokens for prefill, slots for batched decode."""
+    m = wq.shape[0]
+    b, n = xq.shape
+    ng = n // group_size
+    bm = _check_divides(m, block_m or _row_block(m), "m")
+    # a block of every row when they fit (full-dim blocks are legal at any
+    # b), else DEFAULT_BB-row blocks with the last one zero-padded
+    bb = min(b, DEFAULT_BB)
+    row_blocks = -(-b // bb)
+    bp = row_blocks * bb
+    if bp != b:
+        xq = jnp.pad(xq, ((0, bp - b), (0, 0)))
+        xs = jnp.pad(xs, ((0, bp - b), (0, 0)))
+    grid = (m // bm, row_blocks)      # weight row blocks outermost
+
+    out = pl.pallas_call(
+        functools.partial(_gqmm_kernel, group_size=group_size,
+                          group_weights=_GROUP_WEIGHTS[fmt]),
+        grid=grid,
+        name=f"gqmm_{fmt}",
+        in_specs=[
+            pl.BlockSpec((bb, n), lambda i, j: (j, 0)),               # xq
+            pl.BlockSpec((bb, ng), lambda i, j: (j, 0)),              # xs
+            pl.BlockSpec((bm, wq.shape[1]), lambda i, j: (i, 0)),     # wq
+            pl.BlockSpec((bm, ng), lambda i, j: (i, 0)),              # ws
+        ],
+        out_specs=pl.BlockSpec((bb, bm), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((bp, m), jnp.float32),
+        interpret=interpret,
+    )(xq, xs, wq, ws)
+    return out[:b]
 
 
-def gqmm_int4_pallas(
-    wq: jax.Array,   # int8 PACKED (m, n // 2)
+def gqmv_pallas(
+    wq: jax.Array,   # storage (m, n // pack * pack_storage)
     ws: jax.Array,   # f32 (m, n // GS)
-    xq: jax.Array,   # int8 (b, n)
-    xs: jax.Array,   # f32 (b, n // GS)
+    xq: jax.Array,   # int8 (n,)
+    xs: jax.Array,   # f32 (n // GS,)
     *,
     group_size: int,
-    block_b: int | None = None,
+    fmt: str = "int8",
     block_m: int | None = None,
-    block_n: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    return _gqmm_call(_gqmm_int4_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=2, block_b=block_b, block_m=block_m,
-                      block_n=block_n, interpret=interpret)
-
-
-def gqmm_int3_pallas(
-    wq: jax.Array,   # uint8 PACKED (m, n // 8 * 3)
-    ws: jax.Array,   # f32 (m, n // GS)
-    xq: jax.Array,   # int8 (b, n)
-    xs: jax.Array,   # f32 (b, n // GS)
-    *,
-    group_size: int,
-    block_b: int | None = None,
-    block_m: int | None = None,
-    block_n: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    return _gqmm_call(_gqmm_int3_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=8, pack_storage=3, block_b=block_b, block_m=block_m,
-                      block_n=block_n, interpret=interpret)
-
-
-def gqmm_fp8_pallas(
-    wq: jax.Array,   # float8_e4m3fn (m, n)
-    ws: jax.Array,   # f32 (m, n // GS)
-    xq: jax.Array,   # int8 (b, n)
-    xs: jax.Array,   # f32 (b, n // GS)
-    *,
-    group_size: int,
-    block_b: int | None = None,
-    block_m: int | None = None,
-    block_n: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    return _gqmm_call(_gqmm_kernel, wq, ws, xq, xs, group_size=group_size,
-                      pack=1, block_b=block_b, block_m=block_m,
-                      block_n=block_n, interpret=interpret)
+    """out (m,) = W(q) (m, n) @ x(q) (n,) -- the paper's batch-1 core, run
+    as a one-row GQMM."""
+    return gqmm_pallas(wq, ws, xq[None], xs[None], group_size=group_size,
+                       fmt=fmt, block_m=block_m, interpret=interpret)[0]

@@ -11,11 +11,12 @@ Dispatch is two-dimensional:
   'auto'      pallas on TPU, xla elsewhere
 
 kernel hook (weight format): every :class:`~repro.core.quant.QuantFormat`
-names a hook (``fmt.kernel``); ``KERNEL_HOOKS`` maps it to the XLA oracle
-and Pallas kernel pair for both the matrix-vector (GQMV) and batched (GQMM)
-shapes. Registering a new weight format therefore means one
-``QuantFormat`` entry in core/quant.py plus one ``KernelHook`` row here —
-qlinear/policy/engine code never changes (DESIGN.md §8).
+names a hook (``fmt.kernel``); ``KERNEL_HOOKS`` maps it to the XLA oracles
+for both the matrix-vector (GQMV) and batched (GQMM) shapes and to the
+decode stage of the one Pallas kernel (GQMV runs as a one-row GQMM).
+Registering a new weight format therefore means one ``QuantFormat`` entry
+in core/quant.py, one ``KernelHook`` row here and one decode stage in
+kernels/gqmv.py — qlinear/policy/engine code never changes (DESIGN.md §8).
 """
 
 from __future__ import annotations
@@ -42,34 +43,22 @@ def _resolve(impl: str) -> str:
 
 @dataclasses.dataclass(frozen=True)
 class KernelHook:
-    """GQMV/GQMM implementations for one weight storage format. All four
-    callables share the signature (wq, ws, xq, xs, *, group_size[, ...]);
-    ``wq`` is the format's STORAGE array (packed for sub-byte formats),
-    activations are always int8 (W{b}A8)."""
+    """GQMV/GQMM implementations for one weight storage format. Both XLA
+    callables share the signature (wq, ws, xq, xs, *, group_size); ``wq``
+    is the format's STORAGE array (packed for sub-byte formats),
+    activations are always int8 (W{b}A8). ``pallas_fmt`` names the decode
+    stage of the one Pallas kernel (kernels/gqmv.py ``_GROUP_WEIGHTS``)."""
 
     gqmv_xla: Callable
     gqmm_xla: Callable
-    gqmv_pallas: Callable
-    gqmm_pallas: Callable
+    pallas_fmt: str
 
 
 KERNEL_HOOKS: dict[str, KernelHook] = {
-    "gqmv_int8": KernelHook(
-        gqmv_xla=_ref.gqmv_ref, gqmm_xla=_ref.gqmm_ref,
-        gqmv_pallas=_pallas.gqmv_pallas, gqmm_pallas=_pallas.gqmm_pallas,
-    ),
-    "gqmv_int4": KernelHook(
-        gqmv_xla=_ref.gqmv_int4_ref, gqmm_xla=_ref.gqmm_int4_ref,
-        gqmv_pallas=_pallas.gqmv_int4_pallas, gqmm_pallas=_pallas.gqmm_int4_pallas,
-    ),
-    "gqmv_int3": KernelHook(
-        gqmv_xla=_ref.gqmv_int3_ref, gqmm_xla=_ref.gqmm_int3_ref,
-        gqmv_pallas=_pallas.gqmv_int3_pallas, gqmm_pallas=_pallas.gqmm_int3_pallas,
-    ),
-    "gqmv_fp8": KernelHook(
-        gqmv_xla=_ref.gqmv_fp8_ref, gqmm_xla=_ref.gqmm_fp8_ref,
-        gqmv_pallas=_pallas.gqmv_fp8_pallas, gqmm_pallas=_pallas.gqmm_fp8_pallas,
-    ),
+    "gqmv_int8": KernelHook(_ref.gqmv_ref, _ref.gqmm_ref, "int8"),
+    "gqmv_int4": KernelHook(_ref.gqmv_int4_ref, _ref.gqmm_int4_ref, "int4"),
+    "gqmv_int3": KernelHook(_ref.gqmv_int3_ref, _ref.gqmm_int3_ref, "int3"),
+    "gqmv_fp8": KernelHook(_ref.gqmv_fp8_ref, _ref.gqmm_fp8_ref, "fp8"),
 }
 
 
@@ -102,9 +91,9 @@ def gqmv(
     hook = _hook(kernel)
     if impl == "xla":
         return hook.gqmv_xla(wq, ws, xq, xs, group_size=group_size)
-    return hook.gqmv_pallas(
-        wq, ws, xq, xs, group_size=group_size, interpret=(impl == "interpret")
-    )
+    return _pallas.gqmv_pallas(
+        wq, ws, xq, xs, group_size=group_size, fmt=hook.pallas_fmt,
+        interpret=(impl == "interpret"))
 
 
 @partial(jax.jit, static_argnames=("group_size", "impl", "kernel"))
@@ -123,9 +112,9 @@ def gqmm(
     hook = _hook(kernel)
     if impl == "xla":
         return hook.gqmm_xla(wq, ws, xq, xs, group_size=group_size)
-    return hook.gqmm_pallas(
-        wq, ws, xq, xs, group_size=group_size, interpret=(impl == "interpret")
-    )
+    return _pallas.gqmm_pallas(
+        wq, ws, xq, xs, group_size=group_size, fmt=hook.pallas_fmt,
+        interpret=(impl == "interpret"))
 
 
 def paged_attention(

@@ -23,6 +23,16 @@ import jax.numpy as jnp
 from repro.core.quant import QuantizedTensor, unpack_int3, unpack_int4
 
 
+def _sum_groups(scaled: jax.Array) -> jax.Array:
+    """Sum (..., G) over its groups one at a time, in order — the order in
+    which the Pallas kernel adds them, so its integer formats reproduce the
+    GQMV oracles bitwise in interpret mode."""
+    acc = scaled[..., 0]
+    for g in range(1, scaled.shape[-1]):
+        acc = acc + scaled[..., g]
+    return acc
+
+
 @partial(jax.jit, static_argnames=("group_size",))
 def gqmv_ref(
     wq: jax.Array,   # int8 (m, n)
@@ -39,7 +49,7 @@ def gqmv_ref(
     xg = xq.reshape(ng, group_size).astype(jnp.int32)
     group_sums = jnp.einsum("mgk,gk->mg", wg, xg)              # int32 (m, ng)
     scaled = group_sums.astype(jnp.float32) * ws * xs[None, :]  # fp32 (m, ng)
-    return jnp.sum(scaled, axis=-1)
+    return _sum_groups(scaled)
 
 
 @partial(jax.jit, static_argnames=("group_size",))
@@ -75,19 +85,18 @@ def gqmv_int4_ref(
     """Packed-int4 GQMV oracle: unpack nibbles to int8, then Alg. 1 math.
 
     The group sums are exact integers either way; the fp32 stage uses the
-    COMBINED scale ``group_sums * (ws * xs)`` — the same association the
-    Pallas kernels use — so on single-n-block shapes the interpret-mode
-    kernel reproduces this oracle bit-for-bit (multi-block accumulation
-    reassociates the cross-group sum and matches to fp32 rounding).
+    COMBINED scale ``group_sums * (ws * xs)`` and adds the groups in order
+    — the association and order of the Pallas kernel — so the
+    interpret-mode kernel reproduces this oracle bit-for-bit.
     """
-    wq = unpack_int4(wp)
+    wq = unpack_int4(wp, group_size)
     m, n = wq.shape
     ng = n // group_size
     wg = wq.reshape(m, ng, group_size).astype(jnp.int32)
     xg = xq.reshape(ng, group_size).astype(jnp.int32)
     group_sums = jnp.einsum("mgk,gk->mg", wg, xg)               # int32 (m, ng)
     scaled = group_sums.astype(jnp.float32) * (ws * xs[None, :])
-    return jnp.sum(scaled, axis=-1)
+    return _sum_groups(scaled)
 
 
 @partial(jax.jit, static_argnames=("group_size",))
@@ -100,7 +109,7 @@ def gqmm_int4_ref(
     group_size: int,
 ) -> jax.Array:
     """Batched packed-int4 GQMV oracle (see gqmv_int4_ref)."""
-    wq = unpack_int4(wp)
+    wq = unpack_int4(wp, group_size)
     m, n = wq.shape
     b = xq.shape[0]
     ng = n // group_size
@@ -124,14 +133,14 @@ def gqmv_int3_ref(
     """Packed-int3 GQMV oracle: unpack the 3-bit fields to int8, then Alg. 1
     math with the same combined-scale association as the Pallas kernel (see
     gqmv_int4_ref for the bit-exactness argument)."""
-    wq = unpack_int3(wp)
+    wq = unpack_int3(wp, group_size)
     m, n = wq.shape
     ng = n // group_size
     wg = wq.reshape(m, ng, group_size).astype(jnp.int32)
     xg = xq.reshape(ng, group_size).astype(jnp.int32)
     group_sums = jnp.einsum("mgk,gk->mg", wg, xg)               # int32 (m, ng)
     scaled = group_sums.astype(jnp.float32) * (ws * xs[None, :])
-    return jnp.sum(scaled, axis=-1)
+    return _sum_groups(scaled)
 
 
 @partial(jax.jit, static_argnames=("group_size",))
@@ -144,7 +153,7 @@ def gqmm_int3_ref(
     group_size: int,
 ) -> jax.Array:
     """Batched packed-int3 GQMV oracle (see gqmv_int3_ref)."""
-    wq = unpack_int3(wp)
+    wq = unpack_int3(wp, group_size)
     m, n = wq.shape
     b = xq.shape[0]
     ng = n // group_size
@@ -173,7 +182,7 @@ def gqmv_fp8_ref(
     xg = xq.reshape(ng, group_size).astype(jnp.float32)
     group_sums = jnp.einsum("mgk,gk->mg", wg, xg)               # f32 (m, ng)
     scaled = group_sums * (ws * xs[None, :])
-    return jnp.sum(scaled, axis=-1)
+    return _sum_groups(scaled)
 
 
 @partial(jax.jit, static_argnames=("group_size",))

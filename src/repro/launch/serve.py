@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.policy import format_breakdown
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build, load_config
 from repro.serving.engine import InferenceEngine
 
@@ -66,6 +67,7 @@ def main(argv=None):
                          "block/slot tracking, poison-on-free UAF detection, "
                          "NaN/Inf tripwires (equivalent to REPRO_SAN=1)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     sampler_kw = ({"p": args.top_p, "temperature": args.temperature}
                   if args.sampler == "top_p" else None)
     spec_k = args.spec_k or None

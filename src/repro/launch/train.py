@@ -8,6 +8,7 @@ the newest complete checkpoint (fault-tolerant restart path).
 from __future__ import annotations
 
 import argparse
+import os
 
 import jax
 
@@ -15,6 +16,7 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dist import logical
 from repro.dist import sharding as shd
 from repro.ft.elastic import elastic_mesh
+from repro.launch.compile_cache import CHECKOUT, use_compile_cache
 from repro.models.registry import build, load_config
 from repro.optim import adamw
 from repro.train.loop import LoopConfig, make_train_step, run_loop
@@ -29,11 +31,13 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(CHECKOUT, "experiments", "train_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--no-resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = load_config(args.arch)
     if args.reduced:

@@ -1,6 +1,6 @@
 """Edge cases for repro.dist beyond the seed rule table: no-mesh/CPU
 fallback, indivisible-dim degradation, quantized leaves on MoE expert
-weights, pod meshes — plus kernels/gqmv._pick_block block-size selection."""
+weights, pod meshes — plus kernels/gqmv._row_block block-size selection."""
 
 from types import SimpleNamespace
 
@@ -20,7 +20,7 @@ from repro.dist.sharding import (
     param_spec,
     param_specs,
 )
-from repro.kernels.gqmv import _pick_block
+from repro.kernels.gqmv import _row_block
 
 MESH16 = SimpleNamespace(shape={"data": 16, "model": 16},
                          axis_names=("data", "model"))
@@ -147,24 +147,26 @@ def test_logits_spec():
 
 
 # ---------------------------------------------------------------------------
-# kernels/gqmv._pick_block
+# kernels/gqmv._row_block: output-row blocks the TPU compiler accepts
 # ---------------------------------------------------------------------------
 
-def test_pick_block_prime_dim_falls_to_one():
-    assert _pick_block(13, 8) == 1
-    assert _pick_block(997, 256) == 1
+def test_row_block_prefers_default_bm():
+    assert _row_block(2048) == 256
+    assert _row_block(32000) == 256
 
 
-def test_pick_block_dim_below_preferred():
-    assert _pick_block(7, 256) == 7
-    assert _pick_block(384, 1024, multiple_of=128) == 384
+def test_row_block_falls_to_one_lane_tile():
+    assert _row_block(384) == 128
+    assert _row_block(640) == 128
 
 
-def test_pick_block_respects_multiple_of():
-    assert _pick_block(2048, 256, multiple_of=256) == 256
-    assert _pick_block(1024, 1024, multiple_of=256) == 1024
+def test_row_block_full_dim_when_not_lane_multiple():
+    # a block equal to the whole dim is legal at any size
+    assert _row_block(96) == 96
+    assert _row_block(13) == 13
 
 
-def test_pick_block_multiple_of_exceeds_dim_raises():
-    with pytest.raises(ValueError):
-        _pick_block(64, 256, multiple_of=128)
+def test_row_block_divides_every_tinyllama_projection():
+    for m in (256, 2048, 2560, 5632, 11264, 32000):   # kv, o, qkv, ffn, lm head
+        bm = _row_block(m)
+        assert m % bm == 0 and bm % 128 == 0

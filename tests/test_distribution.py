@@ -172,7 +172,9 @@ def test_dryrun_cli_single_cell(tmp_path):
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "internlm2-1.8b",
          "--shape", "prefill_32k", "--mesh", "single", "--out", str(out)],
         capture_output=True, text=True, timeout=1200,
-        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin")},
+        # a CPU test: the child must never reach for an accelerator
+        env={"PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+             "PATH": os.environ.get("PATH", "/usr/bin:/bin")},
         cwd=REPO_ROOT,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr

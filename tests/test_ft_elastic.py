@@ -52,7 +52,9 @@ def _run(devices: int, steps: int, ckdir: str):
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT, str(devices), str(steps), ckdir],
         capture_output=True, text=True, timeout=900, cwd=REPO_ROOT,
-        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin")},
+        # a CPU test: the child must never reach for an accelerator
+        env={"PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+             "PATH": os.environ.get("PATH", "/usr/bin:/bin")},
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     import json

@@ -85,6 +85,38 @@ def test_kvquant_close_to_float_decode(tiny):
     assert agree >= 0.5, agree
 
 
+@pytest.mark.parametrize("kvq", KV_FORMATS)
+def test_paged_quant_kernel_vs_oracle(kvq):
+    """The quantized-pool Pallas kernel (interpret mode) against the XLA
+    gather oracle at tinyllama's head geometry (KV=4, G=8, hd=64): in-VMEM
+    dequantization must equal the oracle's scales-outside-the-dots form
+    up to f32 reassociation."""
+    from repro.kernels.paged_attn import paged_attention_pallas
+    from repro.kernels.ref import paged_attention_ref
+    from repro.models.attention import _quantize_rows
+    from repro.models.common import decode_mask
+
+    rng = np.random.default_rng(1)
+    b, kv, g, hd, nb, bs, mb = 3, 4, 8, 64, 11, 8, 3
+    q = jnp.asarray(rng.normal(size=(b, kv, g, hd)).astype(np.float32))
+    kq, ks = _quantize_rows(
+        jnp.asarray(rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)), kvq)
+    vq, vs = _quantize_rows(
+        jnp.asarray(rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)), kvq)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, nb))[: b * mb].reshape(b, mb).astype(np.int32))
+    pos = jnp.asarray([2, 13, 23], jnp.int32)
+    kn = jnp.asarray(rng.normal(size=(b, kv, hd)).astype(np.float32))
+    vn = jnp.asarray(rng.normal(size=(b, kv, hd)).astype(np.float32))
+    mask = decode_mask(mb * bs, pos, None)
+    kw = dict(scale=hd**-0.5, k_scales=ks, v_scales=vs)
+    ref = paged_attention_ref(q, kq, vq, table, pos, kn, vn, mask, **kw)
+    pal = paged_attention_pallas(q, kq, vq, table, pos, kn, vn, mask, **kw,
+                                 interpret=True)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # cache structure + bytes accounting
 # ---------------------------------------------------------------------------

@@ -63,14 +63,32 @@ def test_int8_via_registry_bit_identical():
 def test_pack_unpack_roundtrip_exact():
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.integers(-7, 8, size=(16, 64)).astype(np.int8))
-    p = pack_int4(q)
+    p = pack_int4(q, 32)
     assert p.shape == (16, 32) and p.dtype == jnp.int8
-    np.testing.assert_array_equal(np.asarray(unpack_int4(p)), np.asarray(q))
+    np.testing.assert_array_equal(np.asarray(unpack_int4(p, 32)), np.asarray(q))
 
 
 def test_pack_odd_axis_raises():
-    with pytest.raises(ValueError, match="even last axis"):
-        pack_int4(jnp.zeros((4, 33), jnp.int8))
+    with pytest.raises(ValueError, match="even group_size dividing"):
+        pack_int4(jnp.zeros((4, 33), jnp.int8), 32)
+    with pytest.raises(ValueError, match="even group_size dividing"):
+        pack_int4(jnp.zeros((4, 66), jnp.int8), 33)
+
+
+@pytest.mark.parametrize("gs", [16, 256])
+def test_pack_int4_byte_holds_both_group_halves(gs):
+    """Storage order the TPU kernel unpacks without a lane interleave: in
+    each group, byte k carries element k (low nibble) and k + gs/2 (high)."""
+    rng = np.random.default_rng(3)
+    q = rng.integers(-7, 8, size=(4, 2 * gs)).astype(np.int8)
+    p = np.asarray(pack_int4(jnp.asarray(q), gs)).astype(np.int32)
+    h = gs // 2
+    for g in range(2):
+        byte = p[:, g * h:(g + 1) * h]
+        lo = ((byte & 0xF) ^ 8) - 8
+        hi = byte >> 4
+        np.testing.assert_array_equal(lo, q[:, g * gs:g * gs + h])
+        np.testing.assert_array_equal(hi, q[:, g * gs + h:(g + 1) * gs])
 
 
 def test_int4_quantize_shapes_and_range():
@@ -81,7 +99,7 @@ def test_int4_quantize_shapes_and_range():
     assert qt.storage_shape == (8, 128)         # packed
     assert qt.shape == qt.logical_shape == (8, 256)
     assert qt.scales.shape == (8, 4)
-    vals = np.asarray(unpack_int4(qt.qvalues))
+    vals = np.asarray(unpack_int4(qt.qvalues, qt.group_size))
     assert vals.max() <= 7 and vals.min() >= -7
     assert vals.max() == 7 or vals.min() == -7  # full range used per Eq. 1
 
@@ -143,16 +161,34 @@ def test_int3_registry_entry():
 def test_pack_unpack_int3_roundtrip_exact():
     rng = np.random.default_rng(21)
     q = jnp.asarray(rng.integers(-3, 4, size=(16, 64)).astype(np.int8))
-    p = pack_int3(q)
+    p = pack_int3(q, 32)
     assert p.shape == (16, 24) and p.dtype == jnp.uint8
-    np.testing.assert_array_equal(np.asarray(unpack_int3(p)), np.asarray(q))
+    np.testing.assert_array_equal(np.asarray(unpack_int3(p, 32)), np.asarray(q))
 
 
 def test_pack_int3_bad_axis_raises():
     with pytest.raises(ValueError, match="divisible by 8"):
-        pack_int3(jnp.zeros((4, 28), jnp.int8))
-    with pytest.raises(ValueError, match="divide by 3"):
-        unpack_int3(jnp.zeros((4, 28), jnp.uint8))
+        pack_int3(jnp.zeros((4, 28), jnp.int8), 28)
+    with pytest.raises(ValueError, match="whole groups"):
+        unpack_int3(jnp.zeros((4, 28), jnp.uint8), 32)
+
+
+@pytest.mark.parametrize("gs", [32, 256])
+def test_pack_int3_planes_per_group(gs):
+    """Storage order the TPU kernel unpacks without a lane interleave: each
+    group is three byte planes of w = gs/8 bytes, and field c of the 24-bit
+    word k is group element c*w + k."""
+    rng = np.random.default_rng(4)
+    q = rng.integers(-3, 4, size=(4, 2 * gs)).astype(np.int8)
+    p = np.asarray(pack_int3(jnp.asarray(q), gs)).astype(np.int32)
+    w = gs // 8
+    for g in range(2):
+        b = p[:, 3 * w * g:3 * w * (g + 1)]
+        word = b[:, :w] | (b[:, w:2 * w] << 8) | (b[:, 2 * w:] << 16)
+        for c in range(8):
+            field = (((word >> (3 * c)) & 7) ^ 4) - 4
+            np.testing.assert_array_equal(
+                field, q[:, g * gs + c * w:g * gs + (c + 1) * w])
 
 
 def test_int3_quantize_shapes_and_range():
@@ -163,7 +199,7 @@ def test_int3_quantize_shapes_and_range():
     assert qt.storage_shape == (8, 96)          # 8 values per 3 bytes
     assert qt.shape == qt.logical_shape == (8, 256)
     assert qt.scales.shape == (8, 4)
-    vals = np.asarray(unpack_int3(qt.qvalues))
+    vals = np.asarray(unpack_int3(qt.qvalues, qt.group_size))
     assert vals.max() <= 3 and vals.min() >= -3
     assert vals.max() == 3 or vals.min() == -3  # full range used per Eq. 1
 
@@ -308,6 +344,29 @@ def test_checkpoint_roundtrip_and_format_mismatch(tmp_path):
     tree8 = {"attn": {"wo": quantize(w, 32, "int8")}, "norm": jnp.ones((8,))}
     with pytest.raises(ValueError, match="quantization mismatch"):
         ckpt.restore(str(tmp_path), tree8)
+
+
+def test_checkpoint_refuses_old_packing_order(tmp_path):
+    """A format-1 manifest packed int4 as adjacent pairs; the group-local
+    unpack would misread it, so restore must refuse instead."""
+    import json
+    import os
+
+    from repro.checkpoint import ckpt
+
+    w = jnp.asarray(np.random.default_rng(7).normal(size=(8, 64)), jnp.float32)
+    tree = {"wo": quantize(w, 32, "int4"), "w8": quantize(w, 32, "int8")}
+    path = ckpt.save(str(tmp_path), 1, tree)
+    ckpt.restore(str(tmp_path), tree)                    # current format: fine
+    manifest = os.path.join(path, ckpt.MANIFEST)
+    with open(manifest) as f:
+        meta = json.load(f)
+    meta["format"] = 1
+    with open(manifest, "w") as f:
+        json.dump(meta, f)
+    with pytest.raises(ValueError, match="older int4/int3 storage order"):
+        ckpt.restore(str(tmp_path), tree)
+    ckpt.restore(str(tmp_path), {"w8": tree["w8"]})     # int8 order unchanged
 
 
 def test_validate_quant_partition():

@@ -106,10 +106,7 @@ def test_compress_roundtrip_error_feedback():
 def test_compressed_psum_unbiased():
     """shard_map over a 1-device axis: compressed psum == plain mean."""
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # moved out of experimental in jax 0.5
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.optim.compress import compressed_psum
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("pod",))
