@@ -1,0 +1,10 @@
+"""CPU tests of the benchmark's own code: run with
+
+    JAX_PLATFORMS=cpu python -m pytest -q bench/tests
+"""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path[:0] = [p for p in (ROOT, os.path.join(ROOT, "src")) if p not in sys.path]
