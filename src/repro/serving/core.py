@@ -22,6 +22,10 @@ DEVICE arrays; the core performs the single host sync per admission wave and
 per round, so the host-sync round-trip budget (DESIGN.md §7,
 analysis/host_sync.py) is enforced lexically on one loop instead of one copy
 per scheduler (DESIGN.md §12).
+
+Each phase of the loop runs inside a ``jax.profiler.TraceAnnotation`` named
+``serve.*`` whose args carry the phase's counts (README.md "Tracing a
+server"); with no profiler running a span costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -34,6 +38,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.sampling import make_sampler
 
@@ -415,6 +420,9 @@ class RecurrentAdapter(ContiguousAdapter):
 # the scheduling core
 # ---------------------------------------------------------------------------
 
+COUNTS = ("prompt_tokens", "prefill_slots", "slot_steps", "live_slot_steps")
+
+
 class SchedulerCore:
     """The one serving loop: admission -> grouped prefill -> jitted
     decode/verify rounds -> finish -> finalize, over any ``CacheAdapter``.
@@ -427,6 +435,21 @@ class SchedulerCore:
 
     Host-sync budget (pinned lexically by analysis/host_sync.py): ONE
     ``jax.device_get`` per admission wave and ONE per decode/verify round.
+
+    ``counts`` holds what the last (or current) ``serve`` did, for an
+    operator to read after it returns:
+
+      prompt_tokens    real prompt tokens prefilled
+      prefill_slots    rows x padded length over every prefill group, so
+                       ``1 - prompt_tokens / prefill_slots`` is the share of
+                       prefill rows that was padding
+      slot_steps       steps x slots over every decode/verify round
+      live_slot_steps  steps x live slots, so ``live_slot_steps /
+                       slot_steps`` is the batch occupancy; in decode rounds
+                       it is also the tokens decoded after the first
+
+    A verify round counts as one step. Each ``serve.round_commit`` span
+    carries the running values.
     """
 
     def __init__(self, engine, adapter: CacheAdapter, *, slots: int = 4,
@@ -449,6 +472,7 @@ class SchedulerCore:
         self._sampler = make_sampler(sampler, **dict(sampler_kw or {}))
         self.last_positions = None     # final per-slot positions (debug)
         self.last_spec_stats = None    # per-serve speculative accounting
+        self.counts = dict.fromkeys(COUNTS, 0)
         if spec_k is not None:
             from repro.serving.spec import NgramDrafter
 
@@ -494,6 +518,8 @@ class SchedulerCore:
         self.last_spec_stats = (
             {"verify_steps": 0, "generated": 0, "drafted": 0, "accepted": 0}
             if self.spec_k is not None else None)
+        counts = self.counts = dict.fromkeys(COUNTS, 0)
+        rnd = 0
 
         def finish(s: int):
             nonlocal cache
@@ -514,33 +540,43 @@ class SchedulerCore:
             # prefill per distinct group length, one scatter-insert per group
             free_slots = [s for s in range(B) if slot_req[s] is None]
             admitted: dict[int, list[tuple[int, Request]]] = defaultdict(list)
-            while free_slots and pending:
-                r = pending[0]
-                if not adapter.can_admit(r, budget(r)):
-                    break                  # backpressure: decode frees space
-                pending.popleft()
-                s = free_slots.pop(0)
-                slot_req[s], slot_toks[s] = r, []
-                live[s] = True
-                if san is not None:
-                    san.on_admit(s, r)
-                adapter.on_admit(s, r, budget(r))
-                admitted[adapter.group_len(len(r.tokens))].append((s, r))
+            with TraceAnnotation("serve.admit") as span:
+                while free_slots and pending:
+                    r = pending[0]
+                    if not adapter.can_admit(r, budget(r)):
+                        break              # backpressure: decode frees space
+                    pending.popleft()
+                    s = free_slots.pop(0)
+                    slot_req[s], slot_toks[s] = r, []
+                    live[s] = True
+                    if san is not None:
+                        san.on_admit(s, r)
+                    adapter.on_admit(s, r, budget(r))
+                    admitted[adapter.group_len(len(r.tokens))].append((s, r))
+                span.set_metadata(
+                    admitted=sum(map(len, admitted.values())),
+                    pending=len(pending))
             staged: list[tuple[list[tuple[int, Request]], jax.Array]] = []
             for length, group in admitted.items():
-                if san is not None:
-                    san.on_prefill_group(group, length)
-                toks_np, lens_np = pad_bucket([r for _, r in group], length)
-                key, kp = jax.random.split(key)
-                t0_d, rows = adapter.prefill(length)(
-                    engine.params, jnp.asarray(toks_np), jnp.asarray(lens_np),
-                    kp)
-                cache = adapter.insert(cache, rows, group, length)
+                n_tokens = sum(len(r.tokens) for _, r in group)
+                with TraceAnnotation("serve.prefill", rows=len(group),
+                                     length=length, tokens=n_tokens):
+                    if san is not None:
+                        san.on_prefill_group(group, length)
+                    toks_np, lens_np = pad_bucket([r for _, r in group], length)
+                    key, kp = jax.random.split(key)
+                    t0_d, rows = adapter.prefill(length)(
+                        engine.params, jnp.asarray(toks_np),
+                        jnp.asarray(lens_np), kp)
+                    cache = adapter.insert(cache, rows, group, length)
+                counts["prompt_tokens"] += n_tokens
+                counts["prefill_slots"] += len(group) * length
                 staged.append((group, t0_d))
             if staged:
                 # ONE host round-trip for the whole admission wave, not one
                 # per group (host-sync round-trip budget: admission + round)
-                first_toks = jax.device_get([t for _, t in staged])
+                with TraceAnnotation("serve.admit_sync", groups=len(staged)):
+                    first_toks = jax.device_get([t for _, t in staged])
                 for (group, _), t0 in zip(staged, first_toks):
                     for (s, r), t in zip(group, t0):
                         slot_toks[s] = [int(t)]
@@ -559,65 +595,93 @@ class SchedulerCore:
                     continue
                 break
 
-            adapter.before_round(pos, live)
-            adapter.check_positions(pos, live)
-            if san is not None:
-                cache = san.pre_round(cache)
-            key, kc = jax.random.split(key)
-            if self.spec_k is not None:
-                # speculative round: draft on the host (per-slot token
-                # history), verify the chunk in one forward pass, keep the
-                # accepted prefix — 1..spec_k tokens per weight stream
-                from repro.serving.spec import draft_chunk, take_accepted
-
-                K = self.spec_k
-                chunk_np = draft_chunk(
-                    self._drafter, tok, live,
-                    lambda s: slot_req[s].tokens + slot_toks[s], K)
-                out_d, n_out_d, cache, pos_d = adapter.verify_round(
-                    engine.params, jnp.asarray(chunk_np), cache,
-                    jnp.asarray(pos), jnp.asarray(live),
-                    jnp.asarray(remaining), kc)
-                out_np, n_out, pos = jax.device_get((out_d, n_out_d, pos_d))
-                pos = pos.copy()
-                st = self.last_spec_stats
-                st["verify_steps"] += 1
-                for s in np.flatnonzero(live):
-                    slot_toks[s].extend(take_accepted(
-                        out_np[s], n_out[s], remaining[s], eos, st, K))
-                    tok[s] = slot_toks[s][-1]
-                    n = budget(slot_req[s])
-                    remaining[s] = n - len(slot_toks[s])
-                    if len(slot_toks[s]) >= n or (
-                            eos is not None and eos in slot_toks[s][:n]):
-                        finish(s)
+            n_live = int(live.sum())
+            with TraceAnnotation("serve.round_prepare", round=rnd, live=n_live):
+                adapter.before_round(pos, live)
+                adapter.check_positions(pos, live)
                 if san is not None:
-                    san.check_round(cache, pos, live)
+                    cache = san.pre_round(cache)
+                key, kc = jax.random.split(key)
+                pos_in, live_in = jnp.asarray(pos), jnp.asarray(live)
+                remaining_in = jnp.asarray(remaining)
+                if self.spec_k is not None:
+                    # speculative round: draft on the host (per-slot token
+                    # history), verify the chunk in one forward pass, keep
+                    # the accepted prefix — 1..spec_k tokens per weight
+                    # stream
+                    from repro.serving.spec import draft_chunk, take_accepted
+
+                    K = self.spec_k
+                    chunk_in = jnp.asarray(draft_chunk(
+                        self._drafter, tok, live,
+                        lambda s: slot_req[s].tokens + slot_toks[s], K))
+                else:
+                    tok_in = jnp.asarray(tok)
+                    keys_in = jax.random.split(kc, self.chunk)
+            if self.spec_k is not None:
+                with TraceAnnotation("serve.round_dispatch", round=rnd):
+                    out_d, n_out_d, cache, pos_d = adapter.verify_round(
+                        engine.params, chunk_in, cache, pos_in, live_in,
+                        remaining_in, kc)
+                with TraceAnnotation("serve.round_sync", round=rnd):
+                    out_np, n_out, pos = jax.device_get((out_d, n_out_d, pos_d))
+                with TraceAnnotation("serve.round_commit", round=rnd) as span:
+                    pos = pos.copy()
+                    st = self.last_spec_stats
+                    st["verify_steps"] += 1
+                    finished = 0
+                    for s in np.flatnonzero(live):
+                        slot_toks[s].extend(take_accepted(
+                            out_np[s], n_out[s], remaining[s], eos, st, K))
+                        tok[s] = slot_toks[s][-1]
+                        n = budget(slot_req[s])
+                        remaining[s] = n - len(slot_toks[s])
+                        if len(slot_toks[s]) >= n or (
+                                eos is not None and eos in slot_toks[s][:n]):
+                            finish(s)
+                            finished += 1
+                    if san is not None:
+                        san.check_round(cache, pos, live)
+                    counts["slot_steps"] += B
+                    counts["live_slot_steps"] += n_live
+                    span.set_metadata(steps=1, live=n_live, finished=finished,
+                                      **counts)
+                rnd += 1
                 continue
-            toks_d, steps_d, cache, pos_d = adapter.decode_round(
-                engine.params, jnp.asarray(tok), cache, jnp.asarray(pos),
-                jnp.asarray(live), jnp.asarray(remaining),
-                jax.random.split(kc, self.chunk))
+            with TraceAnnotation("serve.round_dispatch", round=rnd):
+                toks_d, steps_d, cache, pos_d = adapter.decode_round(
+                    engine.params, tok_in, cache, pos_in, live_in,
+                    remaining_in, keys_in)
             # ONE host sync per round: separate transfers for the step
             # count, the chunk tokens and the positions would each force
             # their own device round-trip on the hot loop
-            steps, toks_all, pos = jax.device_get((steps_d, toks_d, pos_d))
-            toks_np = toks_all[: int(steps)]              # (steps, B)
-            pos = pos.copy()
-            for s in range(B):
-                if not live[s]:
-                    continue
-                n = budget(slot_req[s])
-                slot_toks[s].extend(int(t) for t in toks_np[:, s])
-                tok[s] = slot_toks[s][-1]
-                remaining[s] = n - len(slot_toks[s])
-                done = len(slot_toks[s]) >= n
-                if eos is not None and eos in slot_toks[s][:n]:
-                    done = True
-                if done:
-                    finish(s)
-            if san is not None:
-                san.check_round(cache, pos, live)
+            with TraceAnnotation("serve.round_sync", round=rnd):
+                steps, toks_all, pos = jax.device_get((steps_d, toks_d, pos_d))
+            with TraceAnnotation("serve.round_commit", round=rnd) as span:
+                steps = int(steps)
+                toks_np = toks_all[:steps]                # (steps, B)
+                pos = pos.copy()
+                finished = 0
+                for s in range(B):
+                    if not live[s]:
+                        continue
+                    n = budget(slot_req[s])
+                    slot_toks[s].extend(int(t) for t in toks_np[:, s])
+                    tok[s] = slot_toks[s][-1]
+                    remaining[s] = n - len(slot_toks[s])
+                    done = len(slot_toks[s]) >= n
+                    if eos is not None and eos in slot_toks[s][:n]:
+                        done = True
+                    if done:
+                        finish(s)
+                        finished += 1
+                if san is not None:
+                    san.check_round(cache, pos, live)
+                counts["slot_steps"] += steps * B
+                counts["live_slot_steps"] += steps * n_live
+                span.set_metadata(steps=steps, live=n_live, finished=finished,
+                                  **counts)
+            rnd += 1
 
         self.last_positions = pos.copy()
         if san is not None:

@@ -320,9 +320,13 @@ class PagedAdapter(CacheAdapter):
         return self._insert(cache, rows, tables_g)
 
     def before_round(self, pos, live):
-        for s in range(len(live)):
-            if live[s]:
-                self._ensure_blocks(s, int(pos[s]))
+        with jax.profiler.TraceAnnotation("serve.kv_grow") as span:
+            for s in range(len(live)):
+                if live[s]:
+                    self._ensure_blocks(s, int(pos[s]))
+            span.set_metadata(blocks_live=self.pool.live_blocks,
+                              blocks_free=self.pool.free_blocks,
+                              backlog=self._reserved_backlog())
 
     def check_positions(self, pos, live):
         mb, bs = self.blocks_per_req, self.block_size
