@@ -9,8 +9,9 @@ One chip runs five phases, in order:
 
   device   the platform must be ``tpu``; nothing runs anywhere else
   kernels  GQMV and GQMM in int8/int4/int3/fp8 at tinyllama-1.1b widths,
-           and paged attention over a float pool and an int8 pool, each
-           against its XLA oracle (kernels/ref.py) on the same inputs
+           and paged attention over a float pool and an int8 pool (ragged
+           positions; then with every block only dead table entries name
+           poisoned with NaN), each against its XLA oracle (kernels/ref.py)
   serve    ``repro.launch.serve.main`` at the full width and depth of
            tinyllama-1.1b: int8 W8A8 weights, the default --ragged
            scheduler (paged), 8 requests of up to 128 prompt tokens, 32 new
@@ -99,7 +100,11 @@ def phase_kernels():
                 name = f"{'gqmv' if len(shape) == 1 else 'gqmm b=8'} {fmt} {m}x{n}"
                 check_close(name, got, want, rtol=5e-4, atol=1e-4)
 
-    # paged attention at tinyllama's head geometry over a shuffled pool
+    # paged attention at tinyllama's head geometry over a shuffled pool:
+    # rows at the edges of the kernel's 16-block compute blocks (first
+    # token, pool-block and compute-block boundaries, last slot; MB = 20 is
+    # not a multiple of 16), and every block that only dead table entries
+    # name poisoned for the kernel and zeroed for the oracle
     b, kv, g, hd, bs, mb = 8, 4, 8, 64, 8, 20
     nb = b * mb + 1
     rng = np.random.default_rng(0)
@@ -109,26 +114,42 @@ def phase_kernels():
     vp = jax.random.normal(ks[2], (nb, bs, kv, hd), jnp.float32)
     kn = jax.random.normal(ks[3], (b, kv, hd), jnp.float32)
     vn = jax.random.normal(ks[4], (b, kv, hd), jnp.float32)
-    table = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(b, mb),
-                        jnp.int32)
-    pos = jnp.asarray(rng.integers(0, mb * bs, size=b), jnp.int32)
+    table_np = rng.permutation(np.arange(1, nb)).reshape(b, mb)
+    pos_np = np.array([0, bs - 1, bs, 16 * bs - 1, 16 * bs, mb * bs - 1,
+                       *rng.integers(0, mb * bs, size=2)])
+    dead = np.concatenate([table_np[r, p // bs + 1:]
+                           for r, p in enumerate(pos_np)])
+    table = jnp.asarray(table_np, jnp.int32)
+    pos = jnp.asarray(pos_np, jnp.int32)
     mask = decode_mask(mb * bs, pos)
     kq, k_s = _quantize_rows(kp, "int8")
     vq, v_s = _quantize_rows(vp, "int8")
-    for pool, (k_pages, v_pages, scales) in (
-            ("float", (kp, vp, {})),
-            ("int8", (kq, vq, {"k_scales": k_s, "v_scales": v_s}))):
-        args = (q, k_pages, v_pages, table, pos, kn, vn, mask)
-        got = ops.paged_attention(*args, scale=hd ** -0.5, impl="pallas",
-                                  **scales)
-        with jax.default_matmul_precision("highest"):
-            want = ops.paged_attention(*args, scale=hd ** -0.5, impl="xla",
-                                       **scales)
-        # wider than the interpret-mode 2e-5: on the chip both sides run
-        # their f32 dots as multi-pass bf16 products on the MXU, which
-        # round differently from the CPU's f32 dots
-        check_close(f"paged attention, {pool} pool", got, want,
-                    rtol=1e-4, atol=1e-4)
+    pools = {"float": [kp, vp], "int8": [kq, vq, k_s, v_s]}
+    for pool, leaves in pools.items():
+        for poisoned in (False, True):
+            given = want_in = leaves
+            if poisoned:
+                # int8 rows cannot hold NaN: their scales carry it
+                given = [x.at[dead].set(jnp.nan)
+                         if jnp.issubdtype(x.dtype, jnp.floating) else x
+                         for x in leaves]
+                want_in = [x.at[dead].set(0) for x in leaves]
+
+            def attend(leaves, impl):
+                scales = dict(zip(("k_scales", "v_scales"), leaves[2:]))
+                return ops.paged_attention(
+                    q, *leaves[:2], table, pos, kn, vn, mask,
+                    scale=hd ** -0.5, impl=impl, **scales)
+
+            got = attend(given, "pallas")
+            with jax.default_matmul_precision("highest"):
+                want = attend(want_in, "xla")
+            # wider than the interpret-mode 2e-5: on the chip both sides run
+            # their f32 dots as multi-pass bf16 products on the MXU, which
+            # round differently from the CPU's f32 dots
+            name = f"paged attention, {pool} pool" + (
+                ", dead blocks poisoned" if poisoned else "")
+            check_close(name, got, want, rtol=1e-4, atol=1e-4)
 
 
 def phase_serve():

@@ -138,7 +138,10 @@ def paged_attention(
     Same backend dispatch as gqmv/gqmm: the XLA path gathers the virtual
     sequence through the block table (bit-exact vs the contiguous deferred
     decode on identity tables); the Pallas kernel streams only the live
-    physical blocks HBM->VMEM via scalar-prefetch index maps. With
+    physical blocks HBM->VMEM via scalar-prefetch index maps: each row
+    copies and computes in ceil((pos+1) / 128) grid steps of 128 tokens
+    (at 8-token blocks), and its table entries past the block that holds
+    ``pos`` are never read. With
     ``k_scales``/``v_scales`` the pool holds quantized rows (int8/fp8) and
     dequantization is fused into the attention read — the streamed KV bytes
     stay at storage width."""

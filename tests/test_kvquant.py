@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_cases import BS as PAGED_BS
+from paged_cases import interpret_kernel, paged_case, parity_params
 from repro.models.registry import build, load_config
 from repro.serving.batching import Request, serve_continuous
 from repro.serving.engine import InferenceEngine
@@ -85,34 +87,43 @@ def test_kvquant_close_to_float_decode(tiny):
     assert agree >= 0.5, agree
 
 
-@pytest.mark.parametrize("kvq", KV_FORMATS)
-def test_paged_quant_kernel_vs_oracle(kvq):
+@pytest.mark.parametrize(
+    "kvq,geometry,window,softcap,poison",
+    [pytest.param(kvq, *c.values, id=c.id) for kvq in KV_FORMATS
+     for c in parity_params(prefix=f"{kvq}-", base=kvq)])
+def test_paged_quant_kernel_vs_oracle(kvq, geometry, window, softcap, poison):
     """The quantized-pool Pallas kernel (interpret mode) against the XLA
     gather oracle at tinyllama's head geometry (KV=4, G=8, hd=64): in-VMEM
     dequantization must equal the oracle's scales-outside-the-dots form
-    up to f32 reassociation."""
-    from repro.kernels.paged_attn import paged_attention_pallas
+    up to f32 reassociation. Poisoned: the blocks only dead table entries
+    name carry NaN scales (and NaN rows where the format holds NaN) for
+    the kernel and zeros for the oracle."""
     from repro.kernels.ref import paged_attention_ref
     from repro.models.attention import _quantize_rows
     from repro.models.common import decode_mask
 
     rng = np.random.default_rng(1)
-    b, kv, g, hd, nb, bs, mb = 3, 4, 8, 64, 11, 8, 3
-    q = jnp.asarray(rng.normal(size=(b, kv, g, hd)).astype(np.float32))
-    kq, ks = _quantize_rows(
-        jnp.asarray(rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)), kvq)
-    vq, vs = _quantize_rows(
-        jnp.asarray(rng.normal(size=(nb, bs, kv, hd)).astype(np.float32)), kvq)
-    table = jnp.asarray(
-        rng.permutation(np.arange(1, nb))[: b * mb].reshape(b, mb).astype(np.int32))
-    pos = jnp.asarray([2, 13, 23], jnp.int32)
-    kn = jnp.asarray(rng.normal(size=(b, kv, hd)).astype(np.float32))
-    vn = jnp.asarray(rng.normal(size=(b, kv, hd)).astype(np.float32))
-    mask = decode_mask(mb * bs, pos, None)
-    kw = dict(scale=hd**-0.5, k_scales=ks, v_scales=vs)
-    ref = paged_attention_ref(q, kq, vq, table, pos, kn, vn, mask, **kw)
-    pal = paged_attention_pallas(q, kq, vq, table, pos, kn, vn, mask, **kw,
-                                 interpret=True)
+    hd = 64
+    q, kp, vp, table, pos, kn, vn, dead = paged_case(rng, geometry, 4, 8, hd)
+    kq, ks = (np.array(a) for a in _quantize_rows(jnp.asarray(kp), kvq))
+    vq, vs = (np.array(a) for a in _quantize_rows(jnp.asarray(vp), kvq))
+    pool = [kq, vq, ks, vs]
+    if poison:
+        pool_in = [a.copy() for a in pool]
+        for a, z in zip(pool_in, pool):
+            if jnp.issubdtype(a.dtype, jnp.floating):
+                a[dead] = np.nan
+            z[dead] = 0
+    else:
+        pool_in = pool
+    mask = decode_mask(table.shape[1] * PAGED_BS, pos, window)
+    kw = dict(scale=hd**-0.5, softcap=softcap)
+    kq, vq, ks, vs = pool
+    ref = paged_attention_ref(q, kq, vq, table, pos, kn, vn, mask, **kw,
+                              k_scales=ks, v_scales=vs)
+    kq, vq, ks, vs = pool_in
+    pal = interpret_kernel(q, kq, vq, table, pos, kn, vn, mask, **kw,
+                           k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
                                rtol=2e-5, atol=2e-5)
 

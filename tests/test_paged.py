@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_cases import BS as PAGED_BS
+from paged_cases import interpret_kernel, paged_case, parity_params
 from repro.core import flags
 from repro.models.common import decode_mask
 from repro.models.registry import build, load_config
@@ -339,26 +341,26 @@ def test_paged_validates_capacity_and_layout(tiny, engine):
 # tentpole: kernel parity + sharding rule + snapshot
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("window,softcap", [(None, None), (6, None), (None, 30.0)])
-def test_paged_attention_kernel_vs_oracle(window, softcap):
-    from repro.kernels.paged_attn import paged_attention_pallas
+@pytest.mark.parametrize("geometry,window,softcap,poison", parity_params())
+def test_paged_attention_kernel_vs_oracle(geometry, window, softcap, poison):
+    """Float pool: the Pallas kernel (interpret mode) against the XLA gather
+    oracle over shuffled tables and ragged positions. Poisoned: every block
+    that only dead table entries name is NaN for the kernel and zero for
+    the oracle, so equal outputs show the kernel never reads them."""
     from repro.kernels.ref import paged_attention_ref
 
     rng = np.random.default_rng(0)
-    b, kv, g, hd, nb, bs, mb = 3, 2, 4, 16, 13, 8, 3
-    q = jnp.asarray(rng.normal(size=(b, kv, g, hd)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(nb, bs, kv, hd)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(nb, bs, kv, hd)).astype(np.float32))
-    table = jnp.asarray(
-        rng.permutation(np.arange(1, nb))[: b * mb].reshape(b, mb).astype(np.int32))
-    pos = jnp.asarray([3, 10, 21], jnp.int32)
-    kn = jnp.asarray(rng.normal(size=(b, kv, hd)).astype(np.float32))
-    vn = jnp.asarray(rng.normal(size=(b, kv, hd)).astype(np.float32))
-    mask = decode_mask(mb * bs, pos, window)
-    ref = paged_attention_ref(q, kp, vp, table, pos, kn, vn, mask,
-                              scale=hd**-0.5, softcap=softcap)
-    pal = paged_attention_pallas(q, kp, vp, table, pos, kn, vn, mask,
-                                 scale=hd**-0.5, softcap=softcap, interpret=True)
+    kv, hd = 2, 16
+    q, kp, vp, table, pos, kn, vn, dead = paged_case(rng, geometry, kv, 4, hd)
+    mask = decode_mask(table.shape[1] * PAGED_BS, pos, window)
+    kw = dict(scale=hd**-0.5, softcap=softcap)
+    kp_in, vp_in = kp.copy(), vp.copy()
+    if poison:
+        kp_in[dead], vp_in[dead] = np.nan, np.nan
+        kp[dead], vp[dead] = 0.0, 0.0
+    ref = paged_attention_ref(q, kp, vp, table, pos, kn, vn, mask, **kw)
+    pal = interpret_kernel(q, kp_in, vp_in, table, pos, kn, vn, mask,
+                           **kw)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
                                rtol=2e-5, atol=2e-5)
 

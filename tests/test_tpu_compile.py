@@ -4,7 +4,8 @@ The TPU compiler is installed with jax; it compiles for a described
 ``v5e:2x2`` topology. This catches the block shapes and vector ops the
 chip's kernel compiler refuses, which interpret mode cannot see. Shapes are
 tinyllama-1.1b's: the 5632x2048 and 2048x5632 weight matrices at GS=256,
-and paged attention at KV=4, G=8, hd=64 with 8-token blocks.
+and paged attention at KV=4, G=8, hd=64 with 8-token blocks; paged
+attention also at the benchmark cells' decode shapes.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -93,9 +94,27 @@ def test_gqmm_pads_rows_beyond_one_block(one_chip):
     _compile(partial(gqmm_pallas, group_size=GS, fmt="int8"), wq, ws, xq, xs)
 
 
-@pytest.mark.parametrize("pool", ["bfloat16", "float32", "int8", "float8_e4m3fn"])
-def test_paged_attention_compiles_for_v5e(one_chip, pool):
-    b, kv, g, hd, bs, mb = 8, 4, 8, 64, 8, 20
+# (b, KV, G, hd, MB) of 8-token blocks: tinyllama's heads at 20 blocks (the
+# last of two 16-block compute blocks ragged), and the benchmark cells'
+# decode shapes (bench/workloads/)
+PAGED = {
+    "tinyllama": (8, 4, 8, 64, 20),
+    "dscoder33b-s16": (32, 8, 7, 128, 256),
+    "internlm2-1.8b": (8, 8, 2, 128, 288),
+}
+
+
+@pytest.mark.parametrize("geometry,pool", [
+    *(pytest.param("tinyllama", pool, id=pool)
+      for pool in ["bfloat16", "float32", "int8", "float8_e4m3fn"]),
+    *(pytest.param(cell, pool, id=f"{cell}-{pool}")
+      for cell, pool in [("dscoder33b-s16", "bfloat16"),
+                         ("internlm2-1.8b", "bfloat16"),
+                         ("internlm2-1.8b", "int8")]),
+])
+def test_paged_attention_compiles_for_v5e(one_chip, geometry, pool):
+    b, kv, g, hd, mb = PAGED[geometry]
+    bs = 8
     nb = b * mb + 1
     quant = pool in ("int8", "float8_e4m3fn")
     act = jnp.float32 if pool == "float32" else jnp.bfloat16
