@@ -9,8 +9,9 @@ finished requests read
 - ``program``: the widest gap by which a served token's f32-reference
   logit lies below the reference's best (the number ``correct`` compares);
 - ``control``: the widest gap of the token that the reference computed at
-  the next precision down (int4 weights, ``Reference(weight_bits=4)``)
-  puts first, at the same positions of the same prompts and tokens.
+  the next precision down (int4 weights: the layout's reference hook with
+  ``weight_bits=4``) puts first, at the same positions of the same prompts
+  and tokens.
 
 The limit lies above the largest ``program`` reading and well below the
 smallest ``control`` reading (PERF.md gives both). One JSON line per seed.
@@ -37,9 +38,9 @@ def readings(run, seed: int) -> dict:
     from bench import reference
 
     tokens, rows, targets, n = R.check_sample(run, seed)
-    ref = reference.Reference(run.cfg).logits(seed, tokens, rows)
+    ref = run.layout.Reference(run.cfg).logits(seed, tokens, rows)
     program = reference.gaps(ref, jnp.asarray(targets))
-    ctl = reference.Reference(run.cfg, weight_bits=4).logits(seed, tokens, rows)
+    ctl = run.layout.Reference(run.cfg, weight_bits=4).logits(seed, tokens, rows)
     control = np.asarray(reference.gaps(ref, jnp.argmax(ctl, axis=-1)))[:n]
     return {"seed": seed, "program": float(program.max()),
             "control": float(control.max()), "tokens": n,

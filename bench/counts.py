@@ -7,6 +7,11 @@ in and out. A kernel or step that moves or computes more than this shows
 as a lower share of its roofline. Per-leaf storage bytes follow the
 ``nbytes`` arithmetic of the program's quantized tensors (qvalues +
 scales), copied here rather than imported.
+
+The per-layer arithmetic (``matrices`` and what builds on it) is the dense
+decoder's, and reaches the readers through ``bench/arch/dense.py``;
+``gqmm``, ``paged_attention``, ``weight_bytes`` and ``least_time`` price
+the calls of any layout.
 """
 
 from __future__ import annotations

@@ -1,9 +1,10 @@
 """The system under test, driven through its own serving path.
 
-Builds the program's ``InferenceEngine`` over seeded int8 weights (made on
-the device at storage width, ``bench/weights.py``) and serves a cell's
-offline queue through ``SchedulerCore.serve`` on a ``PagedAdapter``
-subclass. Admission, prefill grouping and decode rounds are the
+Serves a cell's offline queue through the program's ``InferenceEngine``
+and ``SchedulerCore.serve`` on a ``PagedAdapter`` subclass. The model and
+its seeded weights come from the configuration's layout file
+(``bench/arch/<layout>.py``, see ``bench/run.py``); nothing here depends
+on the layout. Admission, prefill grouping and decode rounds are the
 program's own: the subclass changes nothing the program computes or
 decides. It only
 
@@ -36,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
 from repro.serving.paged import PagedAdapter
 
 BLOCK_SIZE = 8      # the program's default KV block
@@ -45,72 +45,6 @@ CHUNK = 4           # the program's default decode round
 
 class WindowClosed(Exception):
     """Raised from a scheduler hook once the measured window has ended."""
-
-
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    if cfg["vocab_size"] % 32:
-        raise ValueError("vocab_size must be a multiple of 32 (no padded rows)")
-    return ModelConfig(
-        arch_id=cfg["name"], family="dense", model_type="decoder_lm",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        group_size=cfg["group_size"], param_dtype=cfg["dtype"],
-        compute_dtype=cfg["dtype"])
-
-
-def served_params(model, cfg: dict, seed: int):
-    """The program's int8 parameter tree, made on the device in one jitted
-    call from the seed: the tree ``quantize_params(model.init(...))`` has,
-    with no float copy of any weight."""
-    from repro.core.policy import quantize_params
-    from repro.core.quant import QuantizedTensor
-
-    want = jax.eval_shape(lambda: quantize_params(
-        model.init(jax.random.PRNGKey(0)), cfg["group_size"]))
-
-    def qt(q, s):
-        return QuantizedTensor(qvalues=q, scales=s,
-                               group_size=cfg["group_size"], fmt="int8")
-
-    def cat(a, b_or_list):
-        parts = [a] + list(b_or_list)
-        return (jnp.concatenate([p[0] for p in parts]),
-                jnp.concatenate([p[1] for p in parts]))
-
-    @jax.jit
-    def make(wkey):
-        def layer(i):
-            lw = W.layer_weights(wkey, i, cfg)
-            # the program fuses q|k|v and gate|up into one matrix each
-            return {"att_norm": lw["att_norm"], "ffn_norm": lw["ffn_norm"],
-                    "wqkv": cat(lw["wq"], [lw["wk"], lw["wv"]]),
-                    "wo": lw["wo"], "w13": cat(lw["w_gate"], [lw["w_up"]]),
-                    "w2": lw["w_down"]}
-
-        ls = jax.lax.map(layer, jnp.arange(cfg["num_hidden_layers"]))
-        return {
-            "embed": qt(*W.embedding(wkey, cfg)),
-            "layers": {"att_norm": ls["att_norm"],
-                       "attn": {"wqkv": qt(*ls["wqkv"]), "wo": qt(*ls["wo"])},
-                       "ffn_norm": ls["ffn_norm"],
-                       "mlp": {"w13": qt(*ls["w13"]), "w2": qt(*ls["w2"])}},
-            "final_norm": W.final_norm(wkey, cfg),
-            "classifier": qt(*W.classifier(wkey, cfg)),
-        }
-
-    params = make(W.weight_key(seed))
-    got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
-    exp = jax.tree.map(lambda a: (a.shape, a.dtype), want)
-    if got != exp:
-        raise AssertionError("seeded weights do not match the program's "
-                             f"parameter tree:\n{got}\n!=\n{exp}")
-    return params
 
 
 @dataclasses.dataclass

@@ -1,11 +1,13 @@
 """GQMM kernel (``gqmm_int8``) roofline share in the traced window: the
-least time of every GQMM call's work (bench/counts.py: int8 ops over the
-int8 peak, or weight + activation bytes over HBM bandwidth, whichever is
-larger) over the device time of the ``gqmm_*`` calls and of the ops feeding
-them (the per-layer weight slices XLA copies out of the stacked weights,
-the activation quantization; ``trace.kernel_time``). Decode calls carry
-every slot's row; prefill calls the padded prompt rows. The run's notes
-give the kernel's own time and share beside it (``trace.kernel_report``)."""
+least time of every GQMM call's work (the layout's ``gqmm_calls``, priced
+by ``counts.least_time``: int8 ops over the int8 peak, or weight +
+activation bytes over HBM bandwidth, whichever is larger) over the device
+time of the ``gqmm_*`` calls and of the ops feeding them (the per-layer
+weight slices XLA copies out of the stacked weights, the activation
+quantization; ``trace.kernel_time``). Decode calls carry every slot's row;
+prefill calls the padded prompt rows, the output head one row a prompt.
+The run's notes give the kernel's own time and share beside it
+(``trace.kernel_report``)."""
 
 import sys
 
@@ -16,7 +18,7 @@ KERNEL = "gqmm_"
 
 
 def read(run):
-    cfg, pk = run.cfg, run.peaks
+    cfg, pk, layout = run.cfg, run.peaks, run.layout
     peak, bw = pk["int8_ops"], pk["hbm_bytes_per_s"]
     calls = []
     for w in run.waves:
@@ -25,12 +27,10 @@ def read(run):
             for s, rid, glen in w.members:
                 groups.setdefault(glen, []).append(rid)
             for glen, rids in groups.items():
-                calls += counts.gqmm_calls(cfg, len(rids) * glen)
-                calls.append(counts.head_call(cfg, len(rids)))
+                calls += layout.gqmm_calls(cfg, len(rids) * glen, len(rids))
     for r in run.rounds:
         if r.traced:
-            step = counts.gqmm_calls(cfg, run.slots) + [counts.head_call(cfg, run.slots)]
-            calls += step * r.n_steps
+            calls += layout.gqmm_calls(cfg, run.slots, run.slots) * r.n_steps
     t = tr.kernel_time(run.trace, KERNEL)
     if not calls or t <= 0:
         return None

@@ -1,13 +1,15 @@
 """Paged-attention kernel (``paged_attention``) roofline share in the
-traced window: per layer and decode step, the least time of the work the
-live rows need (their K/V rows up to the current position, read once;
-bench/counts.py) over the device time of the ``paged_attention`` calls
-and of the ops feeding them (the per-layer slices of the KV pool XLA
-copies out, the mask; ``trace.kernel_time``). The kernel's grid runs over
-every slot and the whole block table; what it does beyond the live rows
-shows as a lower share. The run's notes give the kernel's own time and
-share beside it (``trace.kernel_report``)."""
+traced window: for each decode step, the least time of the work of each of
+the layout's ``attention_calls`` (one a layer that attends: the live rows'
+K/V rows up to the position each layer sees, read once), summed, over the
+device time of the ``paged_attention`` calls and of the ops feeding them
+(the per-layer slices of the KV pool XLA copies out, the mask;
+``trace.kernel_time``). The kernel's grid runs over every slot and the
+whole block table; what it does beyond the live rows shows as a lower
+share. The run's notes give the kernel's own time and share beside it
+(``trace.kernel_report``)."""
 
+import math
 import sys
 
 from bench import counts
@@ -24,9 +26,10 @@ def read(run):
         if r.traced:
             pos = r.pos[r.live]
             for j in range(r.n_steps):
-                least += cfg["num_hidden_layers"] * counts.least_time(
-                    *counts.paged_attention(cfg, pos + j), peak, bw)[0]
-                n += cfg["num_hidden_layers"]
+                calls = run.layout.attention_calls(cfg, pos + j)
+                # fsum rounds once a step: n equal calls read exactly n times one
+                least += math.fsum(counts.least_time(*c, peak, bw)[0] for c in calls)
+                n += len(calls)
     t = tr.kernel_time(run.trace, KERNEL)
     if not n or t <= 0:
         return None

@@ -1,4 +1,9 @@
-"""Plain float32 reference of the served decoder, for the ``correct`` check.
+"""Plain float32 reference of the dense decoder, for the ``correct`` check.
+
+The dense layout's reference hook (``bench/arch/dense.py``); a
+configuration of another layout gets its reference from its own layout
+file, which may reuse the helpers here. ``teacher_forced`` and ``gaps``
+serve every layout.
 
 A Llama-style GQA decoder written straight from its equations: token
 embedding, then per layer RMSNorm -> q/k/v projections -> rotary position

@@ -4,13 +4,18 @@
 
 A cell (``bench/workloads/<cell>.json``) names a model configuration
 (``bench/configs/``), a traffic mix (``bench/traffic/``) and the serving
-shape (slots, max_len). The run
+shape (slots, max_len). The configuration names its layout with
+``"arch"`` (``dense`` where the key is absent): the file
+``bench/arch/<layout>.py`` gives the program's model configuration, the
+seeded weights, the reference and the work counts the readers need
+(``LAYOUT_HOOKS``). Everything else here is the same for every layout. The
+run
 
 1. finds the chip (a TPU it has peaks for; anything else exits non-zero);
-2. sets up: weights from the seed, made on the device at storage width;
-   the program's engine, scheduler and paged pool; every prefill shape
-   the program's admission makes of this traffic, and the decode round,
-   compiled or loaded from ``<checkout>/.jax_cache``;
+2. sets up: weights from the seed, made by the layout on the device at
+   storage width; the program's engine, scheduler and paged pool; every
+   prefill shape the program's admission makes of this traffic, and the
+   decode round, compiled or loaded from ``<checkout>/.jax_cache``;
 3. serves the seed's traffic for ``--seconds`` through
    ``SchedulerCore.serve`` (bench/harness.py), tracing the last seconds of
    the window when ``--trace 1``;
@@ -18,10 +23,11 @@ shape (slots, max_len). The run
    under ``bench/metrics/``: end-to-end with ``--trace 0``, per-layer with
    ``--trace 1``;
 5. frees the program and checks what it served (the tokens of the
-   responses the core built) against the float32 reference
-   (bench/reference.py): the widest gap by which a served token's
-   reference logit lies below the reference's best, over a sample of
-   finished requests that includes the longest, against the cell's limit;
+   responses the core built) against the layout's float32 reference (the
+   dense decoder's is bench/reference.py): the widest gap by which a
+   served token's reference logit lies below the reference's best, over a
+   sample of finished requests that includes the longest, against the
+   cell's limit;
 6. prints the compared numbers with their limits as the last lines of
    standard error, and one JSON object as the last line of standard output.
 """
@@ -39,6 +45,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -58,12 +65,44 @@ def load_json(*parts):
         return json.load(f)
 
 
+# what a layout file (bench/arch/<layout>.py) provides; bench/arch/dense.py
+# documents each
+LAYOUT_HOOKS = ("model_config", "served_params", "Reference", "decode_step",
+                "prefill", "gqmm_calls", "attention_calls")
+
+
+def _load_module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_layout(name: str):
+    """The layout file ``bench/arch/<name>.py``, with every hook."""
+    path = os.path.join(BENCH, "arch", f"{name}.py")
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", name) or not os.path.isfile(path):
+        raise ValueError(f"bench: no layout {name!r}: looked for {path}")
+    mod = _load_module(path, f"bench_arch_{name}")
+    missing = [h for h in LAYOUT_HOOKS if not hasattr(mod, h)]
+    if missing:
+        raise ValueError(f"bench: layout {path} lacks {', '.join(missing)}")
+    return mod
+
+
+def layout_of(cfg: dict):
+    """The layout a configuration names (``"arch"``; ``dense`` by default)."""
+    return load_layout(cfg.get("arch", "dense"))
+
+
 def load_cell(name: str) -> dict:
     """The cell's file with its configuration and mix loaded, and the
-    metrics ``BENCHMARK.json`` gives it."""
+    metrics ``BENCHMARK.json`` gives it. Fails here when the configuration
+    names a layout with no file."""
     cell = load_json(BENCH, "workloads", f"{name}.json")
     cell["name"] = name
     cell["cfg"] = load_json(BENCH, "configs", f"{cell['config']}.json")
+    layout_of(cell["cfg"])
     cell["mix"] = load_json(BENCH, "traffic", f"{cell['traffic']}.json")
     spec = load_json(ROOT, "BENCHMARK.json")
 
@@ -77,10 +116,7 @@ def load_cell(name: str) -> dict:
 
 def reader(metric: str):
     path = os.path.join(BENCH, "metrics", f"{metric}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, f"bench_metric_{metric}").read
 
 
 def find_chip(chips: int) -> tuple[dict, dict]:
@@ -106,6 +142,7 @@ class Run:
     """Everything a metric reader may read."""
     cell: dict
     cfg: dict
+    layout: object                   # the configuration's layout file
     peaks: dict
     slots: int
     pool_blocks: int
@@ -174,8 +211,9 @@ def serve_window(cell: dict, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
     cfg, mix = cell["cfg"], cell["mix"]
-    model = build(harness.model_config(cfg))
-    params = jax.block_until_ready(harness.served_params(model, cfg, seed))
+    layout = layout_of(cfg)
+    model = build(layout.model_config(cfg))
+    params = jax.block_until_ready(layout.served_params(model, cfg, seed))
     stamps.append(("weights", time.perf_counter()))
     engine = InferenceEngine(model, params, cache_len=cell["max_len"],
                              quantize=False, eos_id=None, sanitize=False)
@@ -220,7 +258,7 @@ def serve_window(cell: dict, seed: int, seconds: float, trace: bool,
     failed = sum(len(t) != by_id[rid].max_new for rid, t in served.items())
     peak = max((dv.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for dv in jax.devices())
-    run = Run(cell=cell, cfg=cfg, peaks=peaks, slots=cell["slots"],
+    run = Run(cell=cell, cfg=cfg, layout=layout, peaks=peaks, slots=cell["slots"],
               pool_blocks=adapter.num_blocks - 1, setup_s=t0 - T_START, t0=t0,
               t_close=adapter.t_close, requests=by_id, waves=adapter.waves,
               rounds=rounds, t_admit=adapter.t_admit, t_first=adapter.t_first,
@@ -271,7 +309,7 @@ AT_MOST = ("max_logit_gap", "failed_responses")
 
 
 def check(run: Run, seed: int) -> dict:
-    """The reference comparison: {name: (value, limit)}."""
+    """The comparison with the layout's reference: {name: (value, limit)}."""
     from bench import reference
 
     limit = run.cell["limits"]["max_logit_gap"]
@@ -280,7 +318,7 @@ def check(run: Run, seed: int) -> dict:
     if inputs is None:
         return {"max_logit_gap": (math.inf, limit), **out, "requests_checked": (0, 1)}
     tokens, rows, targets, n = inputs
-    gap = reference.gaps(reference.Reference(run.cfg).logits(seed, tokens, rows), targets)
+    gap = reference.gaps(run.layout.Reference(run.cfg).logits(seed, tokens, rows), targets)
     return {"max_logit_gap": (float(gap.max()), limit), **out,
             "requests_checked": (len({int(i) for i in rows[:, 0]}), 1),
             "tokens_checked": (n, 1)}

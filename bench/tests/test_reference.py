@@ -5,10 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, reference
+from bench import reference
+from bench import run as R
 from bench.tests.record_trace import TINY
 
 SEED = 2**32 + 99
+DENSE = R.load_layout("dense")
 
 
 def program_logits(cfg, prompt, steps):
@@ -17,8 +19,8 @@ def program_logits(cfg, prompt, steps):
     from repro.models.registry import build
     from repro.models.transformer import contiguous_to_paged
 
-    model = build(harness.model_config(cfg))
-    params = harness.served_params(model, cfg, SEED)
+    model = build(DENSE.model_config(cfg))
+    params = DENSE.served_params(model, cfg, SEED)
     total = len(prompt) + steps + 7
     cache_len = -(-total // 8) * 8
     toks = jnp.asarray([prompt], jnp.int32)
@@ -43,14 +45,14 @@ def test_reference_matches_program_prefill_and_paged_decode(dtype):
     served, prog = program_logits(cfg, prompt, 12)
     tokens, rows, targets, n = reference.teacher_forced([prompt], [served], row_multiple=1)
     assert tokens.shape == (1, 512) and list(targets) == served and n == 12
-    ref = np.asarray(reference.Reference(cfg).logits(SEED, tokens, rows))
+    ref = np.asarray(DENSE.Reference(cfg).logits(SEED, tokens, rows))
     scale = np.abs(ref).max()
     err = np.abs(prog - ref).max() / scale
     # int8 activations (W8A8) and, for bfloat16, bf16 rounding over 2 layers
     assert err < (0.03 if dtype == "float32" else 0.06), err
     gaps = np.asarray(reference.gaps(jnp.asarray(ref), jnp.asarray(targets)))
     assert gaps.max() < 0.1 * scale
-    ctl = np.asarray(reference.Reference(cfg, weight_bits=4).logits(SEED, tokens, rows))
+    ctl = np.asarray(DENSE.Reference(cfg, weight_bits=4).logits(SEED, tokens, rows))
     assert np.abs(ctl - ref).max() / scale > 3 * err
 
 
@@ -67,15 +69,15 @@ def test_teacher_forced_pads_to_fixed_sizes_without_changing_a_maximum():
 def test_reference_layers_are_made_one_at_a_time_like_the_whole_model():
     """The reference regenerates each layer alone; the harness makes all
     layers in one call. Both must give the same weights."""
+    import jax
+
+    from bench import weights as W
     from repro.models.registry import build
 
     cfg = dict(TINY)
-    params = harness.served_params(build(harness.model_config(cfg)), cfg, SEED)
-    from bench import weights as W
-
-    lw = W.layer_weights(W.weight_key(SEED), 1, cfg)
-    wqkv = params["layers"]["attn"]["wqkv"]
-    q = np.concatenate([np.asarray(lw[k][0]) for k in ("wq", "wk", "wv")])
-    np.testing.assert_array_equal(np.asarray(wqkv.qvalues[1]), q)
-    np.testing.assert_array_equal(np.asarray(params["layers"]["ffn_norm"][1]),
-                                  np.asarray(lw["ffn_norm"]))
+    params = DENSE.served_params(build(DENSE.model_config(cfg)), cfg, SEED)
+    whole = jax.tree.map(lambda a: np.asarray(a[1]), params["layers"])
+    alone = jax.tree.map(np.asarray, DENSE.served_layer(W.weight_key(SEED), 1, cfg))
+    assert jax.tree.structure(whole) == jax.tree.structure(alone)
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(alone)):
+        np.testing.assert_array_equal(a, b)

@@ -30,13 +30,20 @@ def test_op_names_are_the_ops_own():
 
 
 def test_kernel_events_match_the_calls_the_host_made(small):
+    """The recorded model is a dense one: its layout's counts name one
+    paged-attention call per layer and decode step, and one GQMM call per
+    fused matrix and the output head, per decode step and prefill group."""
+    from bench import run as R
+    from bench.tests.record_trace import TINY
+
     t, c = small
     assert c["device"]["kind"] == "TPU v5 lite"
-    layers = c["layers"]
-    assert tr.op_count(t, "paged_attention") == c["decode_steps"] * layers
-    # four GQMM calls per layer and the output head, per decode step and
-    # per prefill group
-    assert tr.op_count(t, "gqmm_") == (c["decode_steps"] + c["prefill_groups"]) * (4 * layers + 1)
+    layout = R.layout_of(TINY)
+    attention = layout.attention_calls(TINY, np.zeros(8, np.int32))
+    gqmm = layout.gqmm_calls(TINY, 8, 8)
+    assert len(attention) == c["layers"] and len(gqmm) == 4 * c["layers"] + 1
+    assert tr.op_count(t, "paged_attention") == c["decode_steps"] * len(attention)
+    assert tr.op_count(t, "gqmm_") == (c["decode_steps"] + c["prefill_groups"]) * len(gqmm)
 
 
 def test_busy_matches_a_brute_force_timeline(small):
