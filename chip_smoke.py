@@ -11,7 +11,9 @@ One chip runs five phases, in order:
   kernels  GQMV and GQMM in int8/int4/int3/fp8 at tinyllama-1.1b widths,
            and paged attention over a float pool and an int8 pool (ragged
            positions; then with every block only dead table entries name
-           poisoned with NaN), each against its XLA oracle (kernels/ref.py)
+           poisoned with NaN), each against its XLA oracle (kernels/ref.py);
+           GQMM int8/int4 on one layer of a stacked weight, read in place
+           through its layer index, equal to the call on the copied slice
   serve    ``repro.launch.serve.main`` at the full width and depth of
            tinyllama-1.1b: int8 W8A8 weights, the default --ragged
            scheduler (paged), 8 requests of up to 128 prompt tokens, 32 new
@@ -99,6 +101,31 @@ def phase_kernels():
                 want = fn(*args, group_size=GS, impl="xla", kernel=hook)
                 name = f"{'gqmv' if len(shape) == 1 else 'gqmm b=8'} {fmt} {m}x{n}"
                 check_close(name, got, want, rtol=5e-4, atol=1e-4)
+
+    # one layer of a stacked weight read in place through its index: the
+    # same blocks and arithmetic as the call on the copied slice, so equal
+    # (keys of their own: the paged attention below keeps its inputs)
+    m, n, L = 5632, 2048, 3
+    for i, fmt in enumerate(("int8", "int4")):
+        hook = get_format(fmt).kernel
+        kw, kx = jax.random.split(jax.random.PRNGKey(1 + i))
+        ws = [quantize(w, GS, fmt) for w in
+              jax.random.normal(kw, (L, m, n), jnp.float32)]
+        wq = jnp.stack([w.qvalues for w in ws])
+        wsc = jnp.stack([w.scales for w in ws])
+        x = quantize_activation(jax.random.normal(kx, (8, n)), GS)
+        for layer in (0, L - 1):
+            got = ops.gqmm(wq, wsc, x.qvalues, x.scales, jnp.int32(layer),
+                           group_size=GS, impl="pallas", kernel=hook)
+            want = ops.gqmm(wq[layer], wsc[layer], x.qvalues, x.scales,
+                            group_size=GS, impl="pallas", kernel=hook)
+            same = bool(jnp.array_equal(got, want))
+            print(f"  gqmm b=8 {fmt} {m}x{n}, layer {layer} of a {L}-layer "
+                  f"stack read in place: equal to the copied slice: {same}",
+                  flush=True)
+            if not same:
+                raise AssertionError(f"stacked gqmm {fmt} layer {layer}: "
+                                     "differs from the call on its slice")
 
     # paged attention at tinyllama's head geometry over a shuffled pool:
     # rows at the edges of the kernel's 16-block compute blocks (first

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import flags
-from repro.core.quant import QuantizedTensor
+from repro.core.quant import LayerView, QuantizedTensor
 from repro.kernels import ops
 
 __all__ = ["linear", "embedding_lookup", "split_fused"]
@@ -28,8 +28,9 @@ __all__ = ["linear", "embedding_lookup", "split_fused"]
 
 def linear(w, x: jax.Array, *, impl: str = "auto") -> jax.Array:
     """y = x @ W^T for W (out, in); quantized-kernel path when W is a
-    QuantizedTensor (any registered format)."""
-    if isinstance(w, QuantizedTensor):
+    QuantizedTensor (any registered format) or one layer's LayerView of a
+    stacked one."""
+    if isinstance(w, (QuantizedTensor, LayerView)):
         if flags.get("prefill_dequant"):
             # compute-bound many-token passes: one dequant + bf16 MXU matmul
             # beats GQMV's int32 group-sum buffers (flags.py rationale)

@@ -60,6 +60,8 @@ __all__ = [
     "QuantFormat",
     "QuantNumericsError",
     "QuantizedTensor",
+    "LayerView",
+    "layer_of",
     "numerics_checks",
     "numerics_checks_enabled",
     "set_numerics_checks",
@@ -219,6 +221,47 @@ class QuantizedTensor:
         """Stored bits per LOGICAL weight element, scales included
         (e.g. int8/GS=256: 8.125; packed int4/GS=256: 4.125)."""
         return self.storage_bits() / int(np.prod(self.logical_shape))
+
+
+def layer_of(a: jax.Array, layer: jax.Array) -> jax.Array:
+    """Row ``layer`` of a stacked array, sliced as ``lax.scan`` slices its
+    inputs (the index is in range; no wrap-around of negative indices)."""
+    return jax.lax.dynamic_index_in_dim(a, layer, keepdims=False,
+                                        allow_negative_indices=False)
+
+
+@jax.tree_util.register_pytree_with_keys_class
+@dataclasses.dataclass(frozen=True)
+class LayerView:
+    """One layer of a stacked QuantizedTensor (L, m, n), left in place.
+
+    The layer walk (models/transformer.py) hands these to ``linear`` so the
+    GQMM kernel reads the layer's rows straight out of the stack, chosen by
+    ``layer``, instead of a copied (m, n) slice. ``shape`` is the layer's
+    logical shape; ``slice()`` copies the layer out for the consumers that
+    need a plain QuantizedTensor (dequantization).
+    """
+
+    stack: QuantizedTensor
+    layer: jax.Array    # int32 scalar
+
+    def tree_flatten_with_keys(self):
+        ga = jax.tree_util.GetAttrKey
+        return ((ga("stack"), self.stack), (ga("layer"), self.layer)), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    def slice(self) -> QuantizedTensor:
+        return jax.tree.map(lambda a: layer_of(a, self.layer), self.stack)
+
+    def dequantize(self, dtype=jnp.float32) -> jax.Array:
+        return self.slice().dequantize(dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,14 @@ Block layout (what the TPU compiler accepts):
     the lane axis, as in the (bb, bm) output block;
   * the grid runs row blocks of ``wq`` outermost, so each weight byte is
     streamed once per call; activation rows beyond one block are padded up
-    to a whole block.
+    to a whole block;
+  * a layer's weights are read in place: given a stacked ``wq`` (L, m, n')
+    and ``ws`` (L, m, n/GS) with a ``layer`` index, the index rides in as a
+    scalar-prefetch operand and picks the leading coordinate of each weight
+    block, so the layer walk (models/transformer.py) never copies one
+    layer's matrix out of the stack. A 2-D weight (the output head, a
+    caller holding one layer) is a stack of one read at layer 0, so every
+    call takes this one path.
 
 Four weight formats share the dot-product and accumulate stages (see
 core/quant.py registry); ``_GROUP_WEIGHTS`` holds each one's decode stage:
@@ -55,6 +62,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import int3_fields, int4_halves
 
@@ -125,19 +133,22 @@ def _gqmm_kernel(xq_ref, xs_ref, wq_ref, ws_ref, out_ref, *, group_size: int,
 
 
 def gqmm_pallas(
-    wq: jax.Array,   # storage (m, n // pack * pack_storage)
-    ws: jax.Array,   # f32 (m, n // GS)
+    wq: jax.Array,   # storage (m, n // pack * pack_storage), or (L, m, ...)
+    ws: jax.Array,   # f32 (m, n // GS), or (L, m, n // GS)
     xq: jax.Array,   # int8 (b, n)
     xs: jax.Array,   # f32 (b, n // GS)
     *,
     group_size: int,
     fmt: str = "int8",
     block_m: int | None = None,
+    layer: jax.Array | None = None,   # int32 scalar: row of a stacked wq/ws
     interpret: bool = False,
 ) -> jax.Array:
     """out (b, m) f32 = X(q) (b, n) @ W(q)^T for weights stored in registry
-    format ``fmt``; b = tokens for prefill, slots for batched decode."""
-    m = wq.shape[0]
+    format ``fmt``; b = tokens for prefill, slots for batched decode. With
+    ``layer``, ``wq``/``ws`` are stacks and W is their ``layer``-th matrix,
+    read in place; without it W is ``wq`` itself."""
+    m = wq.shape[-2]
     b, n = xq.shape
     ng = n // group_size
     bm = _check_divides(m, block_m or _row_block(m), "m")
@@ -150,37 +161,48 @@ def gqmm_pallas(
         xq = jnp.pad(xq, ((0, bp - b), (0, 0)))
         xs = jnp.pad(xs, ((0, bp - b), (0, 0)))
     grid = (m // bm, row_blocks)      # weight row blocks outermost
-
-    out = pl.pallas_call(
-        functools.partial(_gqmm_kernel, group_size=group_size,
-                          group_weights=_GROUP_WEIGHTS[fmt]),
+    if layer is None:                 # a 2-D weight is a stack of one
+        wq, ws, layer = wq[None], ws[None], 0
+    # index maps take the grid indices, then the scalar-prefetch ref; the
+    # weights' layer axis is squeezed away
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,        # layer
         grid=grid,
-        name=f"gqmm_{fmt}",
         in_specs=[
-            pl.BlockSpec((bb, n), lambda i, j: (j, 0)),               # xq
-            pl.BlockSpec((bb, ng), lambda i, j: (j, 0)),              # xs
-            pl.BlockSpec((bm, wq.shape[1]), lambda i, j: (i, 0)),     # wq
-            pl.BlockSpec((bm, ng), lambda i, j: (i, 0)),              # ws
+            pl.BlockSpec((bb, n), lambda i, j, l: (j, 0)),                 # xq
+            pl.BlockSpec((bb, ng), lambda i, j, l: (j, 0)),                # xs
+            pl.BlockSpec((None, bm, wq.shape[-1]),
+                         lambda i, j, l: (l[0], i, 0)),                    # wq
+            pl.BlockSpec((None, bm, ng), lambda i, j, l: (l[0], i, 0)),    # ws
         ],
-        out_specs=pl.BlockSpec((bb, bm), lambda i, j: (j, i)),
+        out_specs=pl.BlockSpec((bb, bm), lambda i, j, l: (j, i)),
+    )
+    kernel = functools.partial(_gqmm_kernel, group_size=group_size,
+                               group_weights=_GROUP_WEIGHTS[fmt])
+    out = pl.pallas_call(
+        lambda layer_ref, *refs: kernel(*refs),
+        grid_spec=grid_spec,
+        name=f"gqmm_{fmt}",
         out_shape=jax.ShapeDtypeStruct((bp, m), jnp.float32),
         interpret=interpret,
-    )(xq, xs, wq, ws)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), xq, xs, wq, ws)
     return out[:b]
 
 
 def gqmv_pallas(
-    wq: jax.Array,   # storage (m, n // pack * pack_storage)
-    ws: jax.Array,   # f32 (m, n // GS)
+    wq: jax.Array,   # storage (m, n // pack * pack_storage), or (L, m, ...)
+    ws: jax.Array,   # f32 (m, n // GS), or (L, m, n // GS)
     xq: jax.Array,   # int8 (n,)
     xs: jax.Array,   # f32 (n // GS,)
     *,
     group_size: int,
     fmt: str = "int8",
     block_m: int | None = None,
+    layer: jax.Array | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """out (m,) = W(q) (m, n) @ x(q) (n,) -- the paper's batch-1 core, run
     as a one-row GQMM."""
     return gqmm_pallas(wq, ws, xq[None], xs[None], group_size=group_size,
-                       fmt=fmt, block_m=block_m, interpret=interpret)[0]
+                       fmt=fmt, block_m=block_m, layer=layer,
+                       interpret=interpret)[0]

@@ -17,6 +17,12 @@ decode stage of the one Pallas kernel (GQMV runs as a one-row GQMM).
 Registering a new weight format therefore means one ``QuantFormat`` entry
 in core/quant.py, one ``KernelHook`` row here and one decode stage in
 kernels/gqmv.py — qlinear/policy/engine code never changes (DESIGN.md §8).
+
+weight operand: a 2-D (m, n) matrix, or a stacked (L, m, n) one with a
+``layer`` index. The layer walk passes each layer's weights as a
+:class:`~repro.core.quant.LayerView` of the stack, so the Pallas kernel
+reads a layer's weights in place (the index picks its blocks) and no
+per-layer copy is made; the XLA path indexes the stack itself.
 """
 
 from __future__ import annotations
@@ -27,7 +33,13 @@ from typing import Callable
 
 import jax
 
-from repro.core.quant import QuantizedTensor, get_format, quantize_activation
+from repro.core.quant import (
+    LayerView,
+    QuantizedTensor,
+    get_format,
+    layer_of,
+    quantize_activation,
+)
 from repro.kernels import gqmv as _pallas
 from repro.kernels import paged_attn as _paged
 from repro.kernels import ref as _ref
@@ -78,6 +90,7 @@ def gqmv(
     ws: jax.Array,
     xq: jax.Array,
     xs: jax.Array,
+    layer: jax.Array | None = None,
     *,
     group_size: int,
     impl: str = "auto",
@@ -86,14 +99,18 @@ def gqmv(
     """out (m,) = groupwise-quantized W (m,n) @ x (n,). Paper Alg. 1/3.
 
     ``wq`` is the storage array of the format that owns ``kernel`` (plain
-    int8 rows for the default hook, packed nibbles for ``gqmv_int4``)."""
+    int8 rows for the default hook, packed nibbles for ``gqmv_int4``);
+    with ``layer``, ``wq``/``ws`` are (L, ...) stacks and W is layer
+    ``layer`` of them."""
     impl = _resolve(impl)
     hook = _hook(kernel)
     if impl == "xla":
+        if layer is not None:
+            wq, ws = layer_of(wq, layer), layer_of(ws, layer)
         return hook.gqmv_xla(wq, ws, xq, xs, group_size=group_size)
     return _pallas.gqmv_pallas(
         wq, ws, xq, xs, group_size=group_size, fmt=hook.pallas_fmt,
-        interpret=(impl == "interpret"))
+        layer=layer, interpret=(impl == "interpret"))
 
 
 @partial(jax.jit, static_argnames=("group_size", "impl", "kernel"))
@@ -102,19 +119,23 @@ def gqmm(
     ws: jax.Array,
     xq: jax.Array,
     xs: jax.Array,
+    layer: jax.Array | None = None,
     *,
     group_size: int,
     impl: str = "auto",
     kernel: str = "gqmv_int8",
 ) -> jax.Array:
-    """out (b, m) = batched GQMV; b = tokens for prefill / batch for decode."""
+    """out (b, m) = batched GQMV; b = tokens for prefill / batch for decode.
+    ``layer`` as in :func:`gqmv`."""
     impl = _resolve(impl)
     hook = _hook(kernel)
     if impl == "xla":
+        if layer is not None:
+            wq, ws = layer_of(wq, layer), layer_of(ws, layer)
         return hook.gqmm_xla(wq, ws, xq, xs, group_size=group_size)
     return _pallas.gqmm_pallas(
         wq, ws, xq, xs, group_size=group_size, fmt=hook.pallas_fmt,
-        interpret=(impl == "interpret"))
+        layer=layer, interpret=(impl == "interpret"))
 
 
 def paged_attention(
@@ -189,25 +210,28 @@ def paged_verify(
 
 
 def quantized_matmul(
-    x: jax.Array, w: QuantizedTensor, *, impl: str = "auto"
+    x: jax.Array, w: QuantizedTensor | LayerView, *, impl: str = "auto"
 ) -> jax.Array:
     """y = x @ dequant(w).T with run-time int8 activation quantization.
 
     ``x`` is float (..., n); weights are a QuantizedTensor (m, n logical)
-    in ANY registered format with groups along n. Returns float32 (..., m).
+    in ANY registered format with groups along n, or a LayerView of a
+    stacked one (read in place). Returns float32 (..., m).
     This is the composable entry point the model layers use (paper Alg. 2:
     "RMSNorm and quantize x; kernel1(...)"); the format's kernel hook picks
     the matching GQMV/GQMM pair.
     """
+    layer = None
+    if isinstance(w, LayerView):
+        w, layer = w.stack, w.layer
     fmt = get_format(w.fmt)
     xq = quantize_activation(x, group_size=w.group_size)
     lead = x.shape[:-1]
     if lead == ():
-        out = gqmv(w.qvalues, w.scales, xq.qvalues, xq.scales,
-                   group_size=w.group_size, impl=impl, kernel=fmt.kernel)
-        return out
+        return gqmv(w.qvalues, w.scales, xq.qvalues, xq.scales, layer,
+                    group_size=w.group_size, impl=impl, kernel=fmt.kernel)
     flat_q = xq.qvalues.reshape(-1, x.shape[-1])
     flat_s = xq.scales.reshape(-1, xq.scales.shape[-1])
-    out = gqmm(w.qvalues, w.scales, flat_q, flat_s,
+    out = gqmm(w.qvalues, w.scales, flat_q, flat_s, layer,
                group_size=w.group_size, impl=impl, kernel=fmt.kernel)
-    return out.reshape(*lead, w.shape[0])
+    return out.reshape(*lead, w.shape[-2])

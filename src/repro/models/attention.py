@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import flags
 from repro.core.qlinear import linear, split_fused
-from repro.core.quant import FP8_MAX, QuantizedTensor
+from repro.core.quant import FP8_MAX, LayerView, QuantizedTensor
 from repro.dist import logical
 from repro.models.common import (
     NEG_INF,
@@ -797,7 +797,7 @@ def mla_prefill(p, x, cfg: ModelConfig, cache_len: int, *, window=None, lengths=
 
 
 def _maybe_dequant(w):
-    return w.dequantize() if isinstance(w, QuantizedTensor) else w
+    return w.dequantize() if isinstance(w, (QuantizedTensor, LayerView)) else w
 
 
 def mla_decode_deferred(p, x, cache, pos, cfg: ModelConfig, *, window=None):

@@ -5,7 +5,9 @@ minicpm3-4b, dbrx-132b, deepseek-v2-lite-16b, plus the paper's TinyLlama.
 Layers are homogeneous and stacked: init via vmap, forward via lax.scan
 (keeps HLO size O(1) in depth — essential for the 62-layer dry-runs).
 Per-layer local/global alternation (gemma2) is a scanned boolean driving the
-mask, not a structural branch.
+mask, not a structural branch. The serving forwards walk the stack by layer
+index (``_walk_layers``) so the GQMM kernel reads each layer's quantized
+weights in place.
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import flags
 from repro.core.qlinear import embedding_lookup, linear
+from repro.core.quant import LayerView, QuantizedTensor, layer_of
 from repro.dist import logical
 from repro.models import attention as attn
 from repro.models import mlp as mlpmod
@@ -28,6 +31,41 @@ def _layer_windows(cfg: ModelConfig) -> jax.Array:
         return jnp.zeros((cfg.num_layers,), jnp.bool_)
     pat = (cfg.layer_pattern * cfg.num_layers)[: cfg.num_layers]
     return jnp.asarray([c == "L" for c in pat])
+
+
+def _layer_view(layers, l):
+    """Layer ``l`` of the stacked ``layers`` tree. A quantized (L, m, n)
+    matrix becomes a LayerView that the GQMM kernel reads in place; every
+    other leaf (norm gains, float weights, MoE expert stacks) is indexed,
+    as scanning over it would slice it."""
+    def one(leaf):
+        if isinstance(leaf, QuantizedTensor) and leaf.qvalues.ndim == 3:
+            return LayerView(leaf, l)
+        return jax.tree.map(lambda a: layer_of(a, l), leaf)
+
+    return jax.tree.map(one, layers,
+                        is_leaf=lambda x: isinstance(x, QuantizedTensor))
+
+
+def _walk_layers(body, x, params, cfg: ModelConfig, *per_layer):
+    """Scan ``body(x, lp, use_window, *layer_slices) -> (x, out)`` over the
+    layers by index: ``lp`` is ``_layer_view`` of ``params["layers"]``,
+    which stays whole, while ``per_layer`` arrays (a cache or pool) are
+    scanned and arrive sliced. Returns (x, stacked outs).
+
+    The training forward keeps scanning the stack itself: the gradient of
+    an indexed read would scatter into the whole stack at every layer."""
+    layers = params["layers"]
+
+    def step(carry, scanned):
+        x, l = carry
+        use_window, *rest = scanned
+        x, out = body(x, _layer_view(layers, l), use_window, *rest)
+        return (x, l + 1), out
+
+    (x, _), outs = jax.lax.scan(step, (x, jnp.int32(0)),
+                                (_layer_windows(cfg), *per_layer))
+    return x, outs
 
 
 def init_layer(key, cfg: ModelConfig) -> dict:
@@ -213,10 +251,8 @@ def lm_decode_paged(params, token, cache, block_table, pos, cfg: ModelConfig):
     x = embedding_lookup(params["embed"], token, cfg.cdtype())
     if cfg.gemma_norms:
         x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
-    windows = _layer_windows(cfg)
 
-    def body(x, scanned):
-        lp, use_window, layer_cache = scanned
+    def body(x, lp, use_window, layer_cache):
         new_cache = {}
 
         def attn_fn(h):
@@ -250,7 +286,7 @@ def lm_decode_paged(params, token, cache, block_table, pos, cfg: ModelConfig):
             f = rmsnorm(f, lp["post_ffn_norm"], cfg.norm_eps, plus_one=True)
         return x + f, new_cache
 
-    x, new_rows = jax.lax.scan(body, x, (params["layers"], windows, cache))
+    x, new_rows = _walk_layers(body, x, params, cfg, cache)
     new_cache = {
         "k_pages": attn.commit_layers_paged(cache["k_pages"], new_rows["k"],
                                             block_table, pos),
@@ -308,10 +344,8 @@ def lm_verify(params, tokens, cache, pos, cfg: ModelConfig):
     if not pos.ndim:
         pos = jnp.full((tokens.shape[0],), pos, jnp.int32)
     x = _verify_embed(params, tokens, cfg)
-    windows = _layer_windows(cfg)
 
-    def body(x, scanned):
-        lp, use_window, layer_cache = scanned
+    def body(x, lp, use_window, layer_cache):
         rows = {}
 
         def attn_fn(h):
@@ -324,7 +358,7 @@ def lm_verify(params, tokens, cache, pos, cfg: ModelConfig):
 
         return _block(lp, x, cfg, attn_fn), rows
 
-    x, rows = jax.lax.scan(body, x, (params["layers"], windows, cache))
+    x, rows = _walk_layers(body, x, params, cfg, cache)
     return _logits(params, x, cfg), rows
 
 
@@ -349,10 +383,8 @@ def lm_verify_paged(params, tokens, cache, block_table, pos, cfg: ModelConfig):
     if not pos.ndim:
         pos = jnp.full((tokens.shape[0],), pos, jnp.int32)
     x = _verify_embed(params, tokens, cfg)
-    windows = _layer_windows(cfg)
 
-    def body(x, scanned):
-        lp, use_window, layer_cache = scanned
+    def body(x, lp, use_window, layer_cache):
         rows = {}
 
         def attn_fn(h):
@@ -366,7 +398,7 @@ def lm_verify_paged(params, tokens, cache, block_table, pos, cfg: ModelConfig):
 
         return _block(lp, x, cfg, attn_fn), rows
 
-    x, rows = jax.lax.scan(body, x, (params["layers"], windows, cache))
+    x, rows = _walk_layers(body, x, params, cfg, cache)
     return _logits(params, x, cfg), rows
 
 
@@ -423,10 +455,8 @@ def lm_prefill(params, tokens, cfg: ModelConfig, cache_len: int, frontend_embeds
     gathered per row at position lengths[i]-1 instead of the shared last
     column — the fix for sampling the first token from pad-position logits."""
     x = _embed(params, tokens, cfg, frontend_embeds)
-    windows = _layer_windows(cfg)
 
-    def body(x, scanned):
-        lp, use_window = scanned
+    def body(x, lp, use_window):
         cache_out = {}
 
         def attn_fn(h):
@@ -449,7 +479,7 @@ def lm_prefill(params, tokens, cfg: ModelConfig, cache_len: int, frontend_embeds
         x = _block(lp, x, cfg, attn_fn)
         return x, cache_out
 
-    x, cache = jax.lax.scan(body, x, (params["layers"], windows))
+    x, cache = _walk_layers(body, x, params, cfg)
     if lengths is None:
         last = x[:, -1, :]
     else:
@@ -476,10 +506,8 @@ def lm_decode(params, token, cache, pos, cfg: ModelConfig):
     x = embedding_lookup(params["embed"], token, cfg.cdtype())
     if cfg.gemma_norms:
         x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
-    windows = _layer_windows(cfg)
 
-    def body(x, scanned):
-        lp, use_window, layer_cache = scanned
+    def body(x, lp, use_window, layer_cache):
         new_cache = {}
 
         def attn_fn(h):
@@ -525,7 +553,7 @@ def lm_decode(params, token, cache, pos, cfg: ModelConfig):
             f = rmsnorm(f, lp["post_ffn_norm"], cfg.norm_eps, plus_one=True)
         return x + f, new_cache
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], windows, cache))
+    x, new_cache = _walk_layers(body, x, params, cfg, cache)
     if deferred and cfg.mla:
         new_cache = {
             "ckv": attn.commit_layers_bt(cache["ckv"], new_cache["ckv"], pos),

@@ -4,14 +4,19 @@ Kernels execute in interpret mode on the CPU; shapes/dtypes/GS swept.
 tests/test_tpu_compile.py compiles the same kernels for a TPU v5e.
 """
 
-from functools import partial
+from functools import lru_cache, partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
 from repro.core.quant import (
+    LayerView,
+    QuantizedTensor,
+    get_format,
+    quantize,
     quantize_activation,
     quantize_fp8,
     quantize_groupwise,
@@ -369,3 +374,85 @@ def test_int4_quantized_matmul_batched_shapes():
     assert y3.shape == (2, 3, 96)
     # GQMV and GQMM oracles associate the fp32 scale product differently
     np.testing.assert_allclose(np.asarray(y3[0, 0]), np.asarray(y1), rtol=5e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# a layer of a stacked (L, m, n) weight, read in place through a layer index
+# ---------------------------------------------------------------------------
+
+# L, m, n, GS: two 256-row blocks per layer, so the index map's row
+# coordinate moves while its layer coordinate holds
+STACK = (3, 512, 512, 128)
+
+
+@lru_cache(maxsize=None)
+def _stack(fmt):
+    L, m, n, gs = STACK
+    rng = np.random.default_rng(7)
+    ws = [quantize(jnp.asarray(rng.normal(size=(m, n)).astype(np.float32)),
+                   gs, fmt) for _ in range(L)]
+    return (jnp.stack([w.qvalues for w in ws]),
+            jnp.stack([w.scales for w in ws]))
+
+
+def _stack_x(b):
+    n, gs = STACK[2], STACK[3]
+    rng = np.random.default_rng(b)
+    return quantize_activation(
+        jnp.asarray(rng.normal(size=(b, n)).astype(np.float32)), gs)
+
+
+# one compile per shape: ``layer`` is traced, so l = 0 and l = L-1 share it
+_gqmm_interp = jax.jit(partial(gqmm_pallas, group_size=STACK[3], interpret=True),
+                       static_argnames=("fmt",))
+_gqmv_interp = jax.jit(partial(gqmv_pallas, group_size=STACK[3], interpret=True),
+                       static_argnames=("fmt",))
+
+
+@pytest.mark.parametrize("b", [1, 32, 200])   # 1 runs GQMV; 200 pads rows
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("fmt", ["int8", "int4", "int3", "fp8"])
+def test_gqmm_stacked_layer_matches_2d_slice(fmt, layer, b):
+    """The kernel given the whole stack and a layer index matches the XLA
+    oracle on that layer's (m, n) slice, and equals bit for bit the kernel
+    given the slice alone (same blocks and arithmetic, read at layer 0)."""
+    wq, ws = _stack(fmt)
+    l = 0 if layer == "first" else STACK[0] - 1
+    x = _stack_x(b)
+    xq, xs = (x.qvalues[0], x.scales[0]) if b == 1 else (x.qvalues, x.scales)
+    fn = _gqmv_interp if b == 1 else _gqmm_interp
+    got = fn(wq, ws, xq, xs, fmt=fmt, layer=jnp.int32(l))
+    oracle = ops.gqmv if b == 1 else ops.gqmm
+    want = oracle(wq[l], ws[l], xq, xs, group_size=STACK[3], impl="xla",
+                  kernel=get_format(fmt).kernel)
+    assert got.shape == want.shape == ((STACK[1],) if b == 1 else (b, STACK[1]))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=5e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(fn(wq[l], ws[l], xq, xs, fmt=fmt)))
+
+
+@pytest.mark.parametrize("rows", [None, 8])   # GQMV, GQMM
+@pytest.mark.parametrize("fmt", ["int8", "int4", "int3", "fp8"])
+def test_xla_stacked_layer_matches_2d_slice(fmt, rows):
+    """The XLA path indexes the stack itself: a LayerView through
+    ``quantized_matmul`` and a layer index through ``gqmv``/``gqmm`` equal
+    the same calls on the layer's slice."""
+    wq, ws = _stack(fmt)
+    l = STACK[0] - 1
+    hook = get_format(fmt).kernel
+    x = _stack_x(rows or 1)
+    xq, xs = (x.qvalues[0], x.scales[0]) if rows is None else (x.qvalues, x.scales)
+    fn = ops.gqmv if rows is None else ops.gqmm
+    got = fn(wq, ws, xq, xs, jnp.int32(l), group_size=STACK[3], impl="xla",
+             kernel=hook)
+    want = fn(wq[l], ws[l], xq, xs, group_size=STACK[3], impl="xla", kernel=hook)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    stack = QuantizedTensor(wq, ws, STACK[3], fmt)
+    view = LayerView(stack, jnp.int32(l))
+    assert view.shape == (STACK[1], STACK[2])
+    xf = jnp.asarray(np.random.default_rng(3).normal(
+        size=(STACK[2],) if rows is None else (rows, STACK[2])), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(ops.quantized_matmul(xf, view, impl="xla")),
+        np.asarray(ops.quantized_matmul(xf, view.slice(), impl="xla")))
